@@ -60,11 +60,25 @@ def build_model_set(specs=DEFAULT_SPECS,
                     config: GeneratorConfig = BENCH_GEN_CONFIG,
                     cache: str = "default",
                     verbose: bool = True) -> Tuple[ModelSet, float]:
-    """Generate (or load cached) models; returns (set, generation seconds)."""
+    """Generate (or load cached) models; returns (set, generation seconds).
+
+    The cache file records the platform fingerprint it was measured on;
+    a cache from another platform (models fitted on a CPU, read on the
+    chip) refuses to load instead of passing for this platform's models.
+    """
+    from repro.store import PlatformFingerprint, current_fingerprint
+
     MODEL_DIR.mkdir(parents=True, exist_ok=True)
     cache_file = MODEL_DIR / f"{cache}.json"
+    here = current_fingerprint()
     if cache_file.exists():
         data = json.loads(cache_file.read_text())
+        theirs = PlatformFingerprint.from_dict(data.get("fingerprint", {}))
+        if theirs != here:
+            raise RuntimeError(
+                f"{cache_file} was measured on another platform (fields "
+                f"{', '.join(here.mismatches(theirs))} differ); delete it "
+                f"to re-measure here")
         ms = ModelSet()
         for d in data["models"]:
             ms.add(PerformanceModel.from_dict(d))
@@ -86,6 +100,7 @@ def build_model_set(specs=DEFAULT_SPECS,
                   f"{report.seconds:.1f}s", flush=True)
     gen_s = time.perf_counter() - t0
     cache_file.write_text(json.dumps({
+        "fingerprint": here.as_dict(),
         "gen_seconds": gen_s,
         "models": [m.to_dict() for m in ms.models.values()],
     }))

@@ -20,6 +20,8 @@ import platform
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (bench_algorithm_selection, bench_batched_sweep,
                bench_blocksize, bench_cache_effects, bench_contractions,
                bench_einsum_paths, bench_model_accuracy, bench_model_store,
@@ -102,6 +104,7 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_smoke.json",
                     help="smoke-artifact path (with --smoke)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         common.set_smoke(True)
     if args.only and args.suites:

@@ -26,7 +26,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.launch.dryrun import lower_cell                  # noqa: E402
 from repro.perf.predictor import ConfigCandidate, rank_configs  # noqa: E402
-from repro.perf.roofline import RooflineTerms               # noqa: E402
+from repro.perf.roofline import TARGET_KIND, RooflineTerms  # noqa: E402
 
 
 def main():
@@ -43,7 +43,7 @@ def main():
                 flops=meta["flops"], bytes_accessed=meta["bytes"],
                 coll_bytes=meta["coll_bytes"],
                 n_devices=meta["n_devices"],
-                model_flops=meta["model_flops"])
+                model_flops=meta["model_flops"], device_kind=TARGET_KIND)
         return fn
 
     candidates = [

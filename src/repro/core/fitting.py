@@ -182,11 +182,10 @@ def jax_eval_flattened(pts, exps, scl, cof):
     """Evaluate flattened polynomial tensors under jit, in float64."""
     global _JAX_EVAL
     import jax
-    from jax.experimental import enable_x64
 
     if _JAX_EVAL is None:
         _JAX_EVAL = jax.jit(_eval_flattened_impl)
-    with enable_x64():
+    with jax.enable_x64():
         return _JAX_EVAL(pts, exps, scl, cof)
 
 

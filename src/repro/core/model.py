@@ -73,12 +73,11 @@ def _jax_case_eval(pts: np.ndarray, tensors, *,
     """Run the jitted case evaluator in float64 (~1e-8 vs numpy)."""
     global _JAX_CASE_EVAL
     import jax
-    from jax.experimental import enable_x64
 
     if _JAX_CASE_EVAL is None:
         _JAX_CASE_EVAL = jax.jit(_case_eval_impl,
                                  static_argnames="mask_degenerate")
-    with enable_x64():
+    with jax.enable_x64():
         return np.asarray(_JAX_CASE_EVAL(
             pts, *tensors, mask_degenerate=mask_degenerate))
 

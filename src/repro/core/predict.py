@@ -350,12 +350,11 @@ def _fused_predict_jax(inputs, n_configs: int) -> np.ndarray:
     repeated sweep re-uploads nothing."""
     global _FUSED_JIT
     import jax
-    from jax.experimental import enable_x64
 
     if _FUSED_JIT is None:
         _FUSED_JIT = jax.jit(_fused_predict_impl,
                              static_argnames=("n_configs", "std_col"))
-    with enable_x64():
+    with jax.enable_x64():
         return np.asarray(_FUSED_JIT(*inputs, n_configs=n_configs,
                                      std_col=_STD))
 
@@ -500,11 +499,11 @@ class PredictionEngine:
         hit = compiled.__dict__.get("_fused_device_cache")
         if hit is not None and hit[0] is tensors:
             return hit[1]
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         fused = compiled.fused_batch()
-        with enable_x64():
+        with jax.enable_x64():
             inputs = (jnp.asarray(fused.sizes),
                       *(jnp.asarray(t) for t in tensors),
                       jnp.asarray(fused.segments))

@@ -37,10 +37,11 @@ class GeneratorConfig:
     min_width: int = 32
     round_to: int = 8
     max_pieces: int = 128            # safety cap (not in the paper)
-    #: measurement budget (not in the paper): once this many points have
-    #: been *freshly* sampled, the current pieces become terminal — no
-    #: further bisection.  The root grid is always sampled in full, so
-    #: the total may overshoot by at most one grid.  ``None`` = unbounded.
+    #: measurement budget (not in the paper): at most this many points
+    #: are *freshly* sampled, or the root grid if that is larger.  A
+    #: domain whose grid would overrun the budget is not sampled and
+    #: keeps its parent's fit; once the budget is spent nothing is
+    #: bisected further.  ``None`` = unbounded.
     max_points: Optional[int] = None
 
 
@@ -73,8 +74,11 @@ class _Cache:
         self.data: Dict[Point, Stats] = dict(known) if known else {}
         self.measured_points = 0
 
+    def missing(self, points: Sequence[Point]) -> List[Point]:
+        return [p for p in points if p not in self.data]
+
     def get(self, points: Sequence[Point]) -> Dict[Point, Stats]:
-        missing = [p for p in points if p not in self.data]
+        missing = self.missing(points)
         if missing:
             new = self.sample_fn(missing)
             self.data.update(new)
@@ -114,9 +118,10 @@ def refine(domain: Domain, sample_fn: SampleFn,
     basis = monomial_basis(cost_exponents, overfit=config.overfit)
     cache = _Cache(sample_fn, known=known)
     pieces: List[Piece] = []
-    stack = [domain]
+    # (domain, the fit of the domain it was split from; None at the root)
+    stack: List[Tuple[Domain, Optional[Piece]]] = [(domain, None)]
     while stack:
-        dom = stack.pop()
+        dom, parent = stack.pop()
         ppd = _points_per_dim(basis, dom.ndim, config.oversampling)
         pts = grid_points(dom, ppd, kind=config.grid,
                           round_to=config.round_to)
@@ -124,6 +129,13 @@ def refine(domain: Domain, sample_fn: SampleFn,
             # rounding collapsed the grid below the basis size: densify
             pts = grid_points(dom, [p * 2 for p in ppd], kind="cartesian",
                               round_to=config.round_to)
+        if (parent is not None and config.max_points is not None
+                and cache.measured_points + len(cache.missing(pts))
+                > config.max_points):
+            # sampling this half would overrun the budget: it keeps the
+            # fit its parent was given
+            pieces.append(Piece(domain=dom, polys=parent.polys))
+            continue
         stats = cache.get(pts)
         piece, errs = _fit_piece(dom, stats, basis, config.reference_stat)
         err = error_measure(errs, config.error_kind)
@@ -142,7 +154,7 @@ def refine(domain: Domain, sample_fn: SampleFn,
                hi_half.widths() == dom.widths():
                 pieces.append(piece)  # split made no progress
             else:
-                stack.extend((lo_half, hi_half))
+                stack.extend(((lo_half, piece), (hi_half, piece)))
     return pieces
 
 

@@ -24,12 +24,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
+
+_VMEM = pltpu.VMEM
 
 _NEG_INF = -1e30
 
@@ -78,9 +75,11 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def attn_vmem_bytes(bq: int, bkv: int, d: int, itemsize: int = 4) -> int:
-    """VMEM working set of one grid step: q/k/v/o blocks + f32 scratch."""
-    blocks = itemsize * (bq * d + 2 * bkv * d + bq * d)
-    scratch = 4 * (bq + bq + bq * d)       # running max/denominator/acc
+    """VMEM working set of one grid step: the double-buffered q/k/v/o
+    blocks + f32 scratch (running max and denominator are (bq, 1)
+    columns, lane-padded to 128)."""
+    blocks = 2 * itemsize * (bq * d + 2 * bkv * d + bq * d)
+    scratch = 4 * (2 * bq * 128 + bq * d)
     return blocks + scratch
 
 

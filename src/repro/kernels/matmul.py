@@ -2,7 +2,8 @@
 
 The BlockSpec tile sizes (bm, bn, bk) are the TPU counterpart of the paper's
 algorithmic block size b: they fix the VMEM working set
-(bm*bk + bk*bn + 2*bm*bn floats) and the MXU utilization, and are selected by
+(2*(bm*bk + bk*bn + bm*bn) floats of double-buffered blocks plus the
+bm*bn accumulator) and the MXU utilization, and are selected by
 the model-based tile tuner (``repro.perf.tile_tuner``) instead of exhaustive
 sweeps.  Accumulation is f32 in a VMEM scratch buffer across the k grid
 dimension (revisiting-output pattern).
@@ -16,12 +17,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific memory spaces; interpret mode tolerates their absence
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
+
+_VMEM = pltpu.VMEM
 
 
 def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
@@ -38,16 +36,21 @@ def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
 
 
 def vmem_bytes(bm: int, bn: int, bk: int, itemsize: int = 4) -> int:
-    """VMEM working set claimed by one grid step (operands + f32 acc)."""
-    return itemsize * (bm * bk + bk * bn + bm * bn) + 4 * bm * bn
+    """VMEM working set of one grid step: the x, y and output blocks, each
+    double-buffered by the Pallas pipeline, plus the f32 accumulator."""
+    return 2 * itemsize * (bm * bk + bk * bn + bm * bn) + 4 * bm * bn
 
 
 def tile_legal(m: int, n: int, k: int, bm: int, bn: int, bk: int,
                vmem_limit: int = 16 * 2 ** 20) -> bool:
     """MXU alignment (multiples of 128 where the dim allows) + VMEM bound.
 
-    This is the TPU analogue of the paper's cache-driven constraints on
-    leading dimensions and block sizes (§3.1.3, DESIGN.md §2).
+    The bound counts double-buffered blocks (:func:`vmem_bytes`) against
+    the compiler's default scoped VMEM (16 MiB), so every tile admitted
+    here compiles: on TPU v5e, f32 (1024, 1024, 512) counts 20 MiB and is
+    refused, as the compiler refuses it.  This is the TPU analogue of the
+    paper's cache-driven constraints on leading dimensions and block
+    sizes (§3.1.3).
     """
     if m % bm or n % bn or k % bk:
         return False

@@ -7,6 +7,13 @@ within a chunk of length Q the output is a masked, decay-weighted
 in VMEM scratch along the innermost (sequential) grid dimension — the same
 revisiting pattern the flash-attention kernel uses for its softmax state.
 
+Layout: the wrapper moves heads in front of the sequence, so every block's
+last two dimensions are (chunk, P), (chunk, N) or (chunk, 1) — the TPU
+tiling rule wants the last two block dims to be multiples of (8, 128) or
+equal to the array's.  The per-head decay rate is folded into a per-step
+log-decay ``a * dt`` outside the kernel, and the within-chunk cumulative
+sum of it is a lower-triangular matmul (Mosaic has no cumsum).
+
 Grid: (batch, head, n_chunks); b/c projections are group-indexed in the
 BlockSpec (G groups shared across H heads, like GQA).
 """
@@ -18,53 +25,57 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+_HIGHEST = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))        # contract the last dims: a @ b.T
+_TN = (((0,), (0,)), ((), ()))        # contract the first dims: a.T @ b
 
 
-def _ssd_kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, o_ref, h_ref, *,
-                nchunks: int, q: int):
-    ch = pl.program_id(2)
-
-    @pl.when(ch == 0)
+def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, o_ref, h_ref, *,
+                q: int):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = -jnp.exp(alog_ref[0])                 # scalar decay rate (< 0)
-    x = x_ref[0, :, 0, :].astype(jnp.float32)    # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)     # (Q,)
-    b = b_ref[0, :, 0, :].astype(jnp.float32)    # (Q, N)
-    c = c_ref[0, :, 0, :].astype(jnp.float32)    # (Q, N)
+    x = x_ref[0, 0].astype(jnp.float32)       # (Q, P)
+    dt = dt_ref[0, 0]                          # (Q, 1)
+    da = da_ref[0, 0]                          # (Q, 1) log-decay a * dt
+    b = b_ref[0, 0].astype(jnp.float32)       # (Q, N)
+    c = c_ref[0, 0].astype(jnp.float32)       # (Q, N)
 
-    s = jnp.cumsum(a * dt)                    # (Q,) inclusive log-decay
-    i_idx = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-    j_idx = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    seg = s[:, None] - s[None, :]
-    decay = jnp.where(i_idx >= j_idx, jnp.exp(seg), 0.0)
-    # intra-chunk: masked decay-weighted attention
-    g = jnp.dot(c, b.T, preferred_element_type=jnp.float32)
-    g = g * decay * dt[None, :]
-    y = jnp.dot(g, x, preferred_element_type=jnp.float32)     # (Q, P)
-    # inter-chunk: contribution of the carried state
-    h = h_ref[...]                             # (P, N)
-    y = y + jnp.exp(s)[:, None] * jnp.dot(
-        c, h.T, preferred_element_type=jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    # inclusive cumsum s_j = sum_{k<=j} da_k laid along lanes: a ones-row
+    # times the upper-triangular spread of da (exact at HIGHEST precision)
+    spread = jnp.where(row <= col, jnp.broadcast_to(da, (q, q)), 0.0)
+    s_row = jnp.dot(jnp.ones((8, q), jnp.float32), spread,
+                    precision=_HIGHEST,
+                    preferred_element_type=jnp.float32)[:1]   # (1, Q)
+    s_col = jnp.transpose(s_row)               # (Q, 1)
+    decay = jnp.where(row >= col, jnp.exp(s_col - s_row), 0.0)
+    # intra-chunk: masked decay-weighted attention, g[i, j] = dt_j c_i.b_j
+    g = jax.lax.dot_general(c, b * dt, _NT,
+                            preferred_element_type=jnp.float32)
+    y = jnp.dot(g * decay, x, preferred_element_type=jnp.float32)
+    # inter-chunk: contribution of the carried (P, N) state
+    h = h_ref[...]
+    y = y + jnp.exp(s_col) * jax.lax.dot_general(
+        c, h, _NT, preferred_element_type=jnp.float32)
     # state update for the next chunk
-    w = dt * jnp.exp(s[-1] - s)                # (Q,)
-    h_ref[...] = jnp.exp(s[-1]) * h + jnp.dot(
-        x.T, b * w[:, None], preferred_element_type=jnp.float32)
-    o_ref[0, :, 0, :] = y.astype(o_ref.dtype)
+    s_last = jnp.sum(da)                       # scalar: whole-chunk decay
+    w = dt * jnp.exp(s_last - s_col)           # (Q, 1)
+    h_ref[...] = jnp.exp(s_last) * h + jax.lax.dot_general(
+        x, b * w, _TN, preferred_element_type=jnp.float32)
+    o_ref[0, 0] = y.astype(o_ref.dtype)
 
 
 def ssd_vmem_bytes(chunk: int, p: int, n: int, itemsize: int = 4) -> int:
-    """VMEM working set of one grid step: x/dt/b/c/o blocks + f32 state."""
-    blocks = itemsize * (chunk * p + chunk + 2 * chunk * n + chunk * p)
-    return blocks + 4 * p * n              # carried (P, N) state scratch
+    """VMEM working set of one grid step: the double-buffered x/b/c/o
+    blocks, the dt and log-decay columns (lane-padded to 128), and the
+    f32 (P, N) state scratch."""
+    blocks = itemsize * (2 * chunk * p + 2 * chunk * n) + 4 * 2 * chunk * 128
+    return 2 * blocks + 4 * p * n
 
 
 def ssd_grid_steps(b: int, l: int, h: int, chunk: int) -> int:
@@ -81,6 +92,10 @@ def ssd_proxy_problem(chunk: int, p: int, n: int,
     return (1, chunk * steps_per_dim, 1, p, 1, n)
 
 
+def _heads_first(t: jax.Array) -> jax.Array:
+    return jnp.swapaxes(t, 1, 2)
+
+
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd(x: jax.Array, dt: jax.Array, a_log: jax.Array, b: jax.Array,
         c: jax.Array, *, chunk: int = 128,
@@ -93,22 +108,25 @@ def ssd(x: jax.Array, dt: jax.Array, a_log: jax.Array, b: jax.Array,
     rep = H // G
     chunk = min(chunk, L)
     assert L % chunk == 0, (L, chunk)
-    grid = (B, H, L // chunk)
-    return pl.pallas_call(
-        functools.partial(_ssd_kernel, nchunks=grid[2], q=chunk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda bb, h, ch: (bb, ch, h, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bb, h, ch: (bb, ch, h)),
-            pl.BlockSpec((1,), lambda bb, h, ch: (h,)),
-            pl.BlockSpec((1, chunk, 1, N),
-                         lambda bb, h, ch, r=rep: (bb, ch, h // r, 0)),
-            pl.BlockSpec((1, chunk, 1, N),
-                         lambda bb, h, ch, r=rep: (bb, ch, h // r, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, chunk, 1, P),
-                               lambda bb, h, ch: (bb, ch, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        scratch_shapes=[_VMEM((P, N), jnp.float32)],
+    dt_h = _heads_first(dt)[..., None].astype(jnp.float32)     # (B,H,L,1)
+    da_h = dt_h * -jnp.exp(a_log.astype(jnp.float32))[None, :, None, None]
+
+    def step_block(width):
+        return pl.BlockSpec((1, 1, chunk, width),
+                            lambda bb, h, ch: (bb, h, ch, 0))
+
+    def group_block(width):
+        return pl.BlockSpec((1, 1, chunk, width),
+                            lambda bb, h, ch, r=rep: (bb, h // r, ch, 0))
+
+    y = pl.pallas_call(
+        functools.partial(_ssd_kernel, q=chunk),
+        grid=(B, H, L // chunk),
+        in_specs=[step_block(P), step_block(1), step_block(1),
+                  group_block(N), group_block(N)],
+        out_specs=step_block(P),
+        out_shape=jax.ShapeDtypeStruct((B, H, L, P), x.dtype),
+        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a_log, b, c)
+    )(_heads_first(x), dt_h, da_h, _heads_first(b), _heads_first(c))
+    return _heads_first(y)

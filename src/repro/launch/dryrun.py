@@ -32,8 +32,10 @@ from ..configs import SHAPES, all_configs, get_config  # noqa: E402
 from ..distributed.sharding import (cache_specs, data_specs, param_specs,
                                     simple_batch_spec)  # noqa: E402
 from ..perf.analytic import cell_cost  # noqa: E402
-from ..perf.roofline import extract, model_flops_for  # noqa: E402
+from ..perf.roofline import (TARGET_KIND, extract,  # noqa: E402
+                              model_flops_for)
 from ..train.optimizer import AdamW  # noqa: E402
+from .compile_cache import enable_compile_cache  # noqa: E402
 from .mesh import make_production_mesh  # noqa: E402
 from .steps import (abstract_caches, abstract_opt_state, abstract_params,
                     input_specs, make_prefill_step, make_serve_step,
@@ -104,7 +106,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                            NamedSharding(mesh, P())),
             donate_argnums=(0, 1),
         )
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_abs, opt_abs, specs["batch"])
     elif shape.kind == "prefill":
         bspecs = data_specs(mesh, shape.global_batch, strategy)
@@ -118,7 +120,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             out_shardings=(NamedSharding(mesh, logit_spec),
                            _sh(mesh, cspecs)),
         )
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_abs, specs["batch"])
     else:  # decode
         cspecs = cache_specs(cfg, mesh, shape.global_batch)
@@ -133,7 +135,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                            _sh(mesh, cspecs)),
             donate_argnums=(1,),
         )
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_abs, specs["caches"],
                                    specs["token"], specs["index"])
     t_lower = time.perf_counter() - t0
@@ -144,7 +146,8 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     terms = extract(compiled, n_dev,
                     model_flops=model_flops_for(cfg, shape),
                     analytic=cell_cost(cfg, shape,
-                                       remat_policy=remat_policy))
+                                       remat_policy=remat_policy),
+                    device_kind=TARGET_KIND)
     mem = compiled.memory_analysis()
     meta = {
         "arch": arch, "shape": shape_name,
@@ -220,6 +223,7 @@ def main():
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.all:
         cells = all_cells()
     else:

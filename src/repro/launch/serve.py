@@ -11,6 +11,7 @@ import numpy as np
 from ..configs import get_config, reduced
 from ..models import init_params
 from ..serve.engine import Request, ServeEngine
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--ctx", type=int, default=128)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced(get_config(args.arch))
     if not cfg.causal:

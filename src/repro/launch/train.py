@@ -17,6 +17,7 @@ from ..configs import get_config, reduced
 from ..train.data import DataConfig
 from ..train.optimizer import AdamW
 from ..train.train_loop import TrainConfig, train
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--d-model", type=int, default=128)
     ap.add_argument("--layers", type=int, default=2)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

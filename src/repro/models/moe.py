@@ -14,6 +14,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ArchConfig
 
@@ -38,12 +39,11 @@ def init_moe(cfg: ArchConfig, key, dtype) -> MoEParams:
 
 
 def _maybe_constrain(x: jax.Array, spec) -> jax.Array:
-    """Sharding constraint that degrades to a no-op outside a mesh."""
-    try:
-        from jax.sharding import PartitionSpec as P
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
+    """Sharding constraint under a mesh (``jax.set_mesh``); a no-op
+    outside one."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def moe_forward(cfg: ArchConfig, p: MoEParams, x: jax.Array,

@@ -22,6 +22,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, PartitionSpec as P
 
 from ..configs.base import ArchConfig, LayerSpec
 from .attention import AttnParams, attn_forward, init_attn
@@ -67,6 +68,18 @@ def _init_block(cfg: ArchConfig, spec: LayerSpec, key, dtype) -> Params:
 
 
 def init_params(cfg: ArchConfig, key, dtype=jnp.bfloat16) -> Params:
+    """Random parameters from ``key``, built in one jitted program.
+
+    Each pattern position's blocks come out of a ``lax.scan`` over the
+    periods, whose stacked outputs are written in place: the device holds
+    the weights plus about one layer of temporaries, never every layer
+    twice (the per-layer arrays and their stack).
+    """
+    return _init_params(cfg, key, dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _init_params(cfg: ArchConfig, key, dtype) -> Params:
     k_embed, k_blocks, k_head = jax.random.split(key, 3)
     params: Params = {}
     if cfg.frontend == "none":
@@ -81,19 +94,15 @@ def init_params(cfg: ArchConfig, key, dtype=jnp.bfloat16) -> Params:
         params["head"] = (jax.random.normal(
             k_head, (cfg.d_model, cfg.vocab)) * cfg.d_model ** -0.5
         ).astype(dtype)
-    np_ = n_periods(cfg)
-    block_keys = jax.random.split(k_blocks, np_ * len(cfg.block_pattern))
-    per_position = []
+    period = len(cfg.block_pattern)
+    block_keys = jax.random.split(k_blocks, n_periods(cfg) * period)
+    params["blocks"] = {}
     for pi, spec in enumerate(cfg.block_pattern):
-        stacked = [
-            _init_block(cfg, spec, block_keys[per * len(cfg.block_pattern)
-                                              + pi], dtype)
-            for per in range(np_)
-        ]
-        per_position.append(jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *stacked))
-    params["blocks"] = {f"p{pi}": blk for pi, blk in
-                        enumerate(per_position)}
+        # block (per, pi) takes key per * period + pi
+        _, stacked = jax.lax.scan(
+            lambda _, k, spec=spec: (None, _init_block(cfg, spec, k, dtype)),
+            None, block_keys[pi::period])
+        params["blocks"][f"p{pi}"] = stacked
     params["final_norm"] = jnp.zeros((cfg.d_model,), dtype=dtype)
     return params
 
@@ -141,7 +150,13 @@ def _block_forward(cfg: ArchConfig, spec: LayerSpec, p: Params,
 
 def _embed(cfg: ArchConfig, params: Params, inputs: jax.Array) -> jax.Array:
     if cfg.frontend == "none":
-        return params["embed"][inputs]
+        mesh = jax.sharding.get_abstract_mesh()
+        if AxisType.Explicit not in mesh.axis_types:
+            return params["embed"][inputs]
+        # with explicit mesh axes the vocab-sharded table's gather has no
+        # unambiguous output sharding: the rows take the tokens' sharding
+        spec = jax.typeof(inputs).sharding.spec
+        return params["embed"].at[inputs].get(out_sharding=P(*spec, None))
     return jnp.einsum("bsf,fd->bsd", inputs, params["frontend_proj"])
 
 

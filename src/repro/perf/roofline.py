@@ -20,11 +20,39 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
 
-# ----------------------------------------------------- TPU v5e constants --
+# ------------------------------------------------------------------ peaks --
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+@dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops: float       # bf16 FLOP/s per chip
+    hbm_bw: float      # HBM bytes/s per chip
+    ici_bw: float      # interconnect bytes/s per link
+    source: str
+
+
+#: published peaks keyed by ``jax.devices()[0].device_kind``
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip "
+               "(four links of 400 Gbit/s = 50 GB/s each)"),
+}
+
+#: the chip the dry-run compiles for and the analytic models describe
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; a kind without published
+    peaks is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}"
+                       f"; add them with their source to "
+                       f"repro.perf.roofline.PEAKS")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -109,6 +137,12 @@ class RooflineTerms:
     model_flops: float = 0.0         # 6*N*D (global, useful FLOPs)
     hlo_flops: float = 0.0           # raw cost_analysis value (body-once)
     hlo_bytes: float = 0.0
+    #: whose peaks the terms divide by (``TARGET_KIND`` for the dry-run)
+    device_kind: str = field(kw_only=True)
+
+    @property
+    def peaks(self) -> Peaks:
+        return peaks(self.device_kind)
 
     @property
     def coll_total(self) -> int:
@@ -116,15 +150,15 @@ class RooflineTerms:
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_accessed / HBM_BW
+        return self.bytes_accessed / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.coll_total / ICI_BW
+        return self.coll_total / self.peaks.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -147,7 +181,7 @@ class RooflineTerms:
         """Useful-compute seconds / bound seconds (the score per cell)."""
         if self.bound_s <= 0:
             return 0.0
-        useful_s = self.model_flops / self.n_devices / PEAK_FLOPS
+        useful_s = self.model_flops / self.n_devices / self.peaks.flops
         return useful_s / self.bound_s
 
     def as_dict(self) -> dict:
@@ -165,8 +199,9 @@ class RooflineTerms:
 
 
 def extract(compiled, n_devices: int, model_flops: float = 0.0,
-            analytic=None) -> RooflineTerms:
-    """Build RooflineTerms from a compiled executable.
+            analytic=None, *, device_kind: str) -> RooflineTerms:
+    """Build RooflineTerms from ``compiled``, an executable for a
+    ``device_kind`` chip.
 
     ``analytic`` (a ``perf.analytic.CellCost``) supplies GLOBAL flops/bytes;
     when given it overrides cost_analysis (which counts while bodies once —
@@ -189,7 +224,7 @@ def extract(compiled, n_devices: int, model_flops: float = 0.0,
     return RooflineTerms(flops=flops, bytes_accessed=nbytes,
                          coll_bytes=coll, n_devices=n_devices,
                          model_flops=model_flops, hlo_flops=hlo_flops,
-                         hlo_bytes=hlo_bytes)
+                         hlo_bytes=hlo_bytes, device_kind=device_kind)
 
 
 def model_flops_for(cfg, shape) -> float:

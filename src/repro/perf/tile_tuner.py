@@ -41,8 +41,10 @@ import math
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+
 from ..kernels.matmul import tile_legal
-from .roofline import HBM_BW, PEAK_FLOPS
+from .roofline import peaks
 
 _GRID_STEP_OVERHEAD_S = 1e-6
 _CANDIDATES = (128, 256, 512, 1024)
@@ -77,12 +79,15 @@ class TileChoice:
 
 
 def predict_tile_time(m: int, n: int, k: int, bm: int, bn: int,
-                      bk: int, itemsize: int = 2) -> float:
-    """The analytic three-term estimate — the measured path's oracle."""
+                      bk: int, itemsize: int = 2, *,
+                      device_kind: str) -> float:
+    """The analytic three-term estimate — the measured path's oracle —
+    against ``device_kind``'s published peaks (an unknown kind raises)."""
+    chip = peaks(device_kind)
     eff = _mxu_eff(bm) * _mxu_eff(bn) * _mxu_eff(bk)
-    compute = 2.0 * m * n * k / (PEAK_FLOPS * eff)
+    compute = 2.0 * m * n * k / (chip.flops * eff)
     traffic = itemsize * (m * k * (n / bn) + k * n * (m / bm) + m * n)
-    memory = traffic / HBM_BW
+    memory = traffic / chip.hbm_bw
     steps = (m // bm) * (n // bn) * (k // bk)
     return max(compute, memory) + steps * _GRID_STEP_OVERHEAD_S
 
@@ -107,7 +112,8 @@ def rank_tiles(m: int, n: int, k: int, *,
                vmem_limit: int = 16 * 2 ** 20,
                candidates: Sequence[int] = _CANDIDATES,
                stat: str = "med", transfer: bool = True,
-               itemsize: int = 4) -> List[TileChoice]:
+               itemsize: int = 4,
+               device_kind: Optional[str] = None) -> List[TileChoice]:
     """Every legal tile config ranked fastest-predicted first.
 
     With a ``session`` (a :class:`~repro.tc.PredictorSession`) and
@@ -117,16 +123,21 @@ def rank_tiles(m: int, n: int, k: int, *,
     measurements already in the session's suite — including ones
     warm-loaded from a :class:`~repro.store.ModelStore` — are never
     re-taken.  ``analytic=True`` (or ``session=None``) ranks with the
-    deterministic three-term model instead — the hardware-free fallback
-    and the measured path's sanity oracle.
+    deterministic three-term model instead — the hardware-free path and
+    the measured path's sanity oracle — against the published peaks of
+    ``device_kind``, by default the attached device's (a kind with no
+    published peaks, such as a CPU, raises).
     """
     legal = _legal_candidates(m, n, k, candidates, vmem_limit)
     if not legal:
         raise ValueError(f"no legal tile for ({m},{n},{k}) "
                          f"within VMEM {vmem_limit}")
     if analytic or session is None:
+        if device_kind is None:
+            device_kind = jax.devices()[0].device_kind
         ranked = [TileChoice(bm, bn, bk,
-                             predict_tile_time(m, n, k, bm, bn, bk))
+                             predict_tile_time(m, n, k, bm, bn, bk,
+                                               device_kind=device_kind))
                   for bm, bn, bk in legal]
         ranked.sort(key=lambda t: (t.predicted_s, (t.bm, t.bn, t.bk)))
         return ranked
@@ -145,15 +156,17 @@ def select_tiles(m: int, n: int, k: int, *,
                  vmem_limit: int = 16 * 2 ** 20,
                  candidates: Sequence[int] = _CANDIDATES,
                  stat: str = "med", transfer: bool = True,
-                 itemsize: int = 4) -> TileChoice:
+                 itemsize: int = 4,
+                 device_kind: Optional[str] = None) -> TileChoice:
     """Pick (bm, bn, bk) without executing any candidate at problem size
     (the paper's prediction-not-execution principle): the argmin of
     :func:`rank_tiles` — measured models through the session's device
     facet by default, the analytic three-term model with
-    ``analytic=True`` or no session."""
+    ``analytic=True`` or no session (against ``device_kind``'s peaks)."""
     return rank_tiles(m, n, k, session=session, analytic=analytic,
                       vmem_limit=vmem_limit, candidates=candidates,
-                      stat=stat, transfer=transfer, itemsize=itemsize)[0]
+                      stat=stat, transfer=transfer, itemsize=itemsize,
+                      device_kind=device_kind)[0]
 
 
 def tile_table(shapes: Sequence[Tuple[int, int, int]],

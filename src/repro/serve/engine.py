@@ -22,9 +22,10 @@ predictions.
 
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +50,14 @@ class Request:
     prompt: np.ndarray           # (S,) int32
     max_new_tokens: int = 16
     out_tokens: List[int] = field(default_factory=list)
+    #: keep every output token's step logits in :attr:`out_logits`.  Off
+    #: by default: each kept entry pins a whole (slots, 1, V) step output
+    #: on the device for as long as the request lives.
+    keep_logits: bool = False
+    #: with :attr:`keep_logits`, per output token, the step logits it was
+    #: chosen from and the slot row: ``(logits (slots, 1, V) device array,
+    #: slot)`` — a reference to the step's own output, no copy and no sync
+    out_logits: List[Tuple[jax.Array, int]] = field(default_factory=list)
     done: bool = False
     arrival_s: float = 0.0       # open-loop arrival time (run() clock)
     submitted_s: Optional[float] = None   # entered the waiting queue
@@ -99,6 +108,22 @@ class EngineStats:
                                          percentile))
 
 
+def _step(cfg: ArchConfig, precision: Optional[str], params, caches, token,
+          index):
+    """The engine's decode step, its matmuls at ``precision`` (``None``:
+    the backend's default)."""
+    if precision is None:
+        return decode_step(cfg, params, caches, token, index)
+    with jax.default_matmul_precision(precision):
+        return decode_step(cfg, params, caches, token, index)
+
+
+def _reset_slot(caches, slot):
+    """Zero one batch row (axis 1 of every cache leaf: (periods, batch,
+    ...)) of the decode state."""
+    return jax.tree_util.tree_map(lambda c: c.at[:, slot].set(0), caches)
+
+
 class ServeEngine:
     """Static-batch serving engine (batch slots, per-slot position).
 
@@ -110,10 +135,18 @@ class ServeEngine:
     :meth:`advance`) folds prefill tokens into the same fused steps that
     advance decode lanes — prompt processing then costs no dedicated
     engine steps while decode work exists.
+
+    ``matmul_precision`` sets the decode step's matmul precision (a
+    ``jax.default_matmul_precision`` name; ``None`` keeps the backend's
+    default).  On a TPU the default computes a float32 matmul as one
+    bfloat16 pass, and XLA then converts every layer's float32 weights to
+    bfloat16 ahead of the layer scan: mamba2-2.7b's float32 decode step
+    needs 17.35 GB that way, and 13.6 GB at ``"highest"``.
     """
 
     def __init__(self, cfg: ArchConfig, params, *, batch_slots: int = 4,
-                 ctx_len: int = 512, dtype=jnp.float32, scheduler=None):
+                 ctx_len: int = 512, dtype=jnp.float32, scheduler=None,
+                 matmul_precision: Optional[str] = None):
         assert cfg.causal, "decoder-only architectures serve"
         self.cfg = cfg
         self.params = params
@@ -127,8 +160,18 @@ class ServeEngine:
         self.prefill_done: Dict[int, int] = {}   # prompt tokens consumed
         self.stats = EngineStats()
         self.scheduler = scheduler
-        self._decode = jax.jit(
-            lambda p, c, t, i: decode_step(cfg, p, c, t, i))
+        # caches are donated: the step updates them in place instead of
+        # holding two copies of every slot's state on the device
+        self._decode = jax.jit(functools.partial(_step, cfg,
+                                                 matmul_precision),
+                               donate_argnums=(1,))
+        self._reset = jax.jit(_reset_slot, donate_argnums=(0,))
+
+    def _admit(self, slot: int) -> None:
+        """Zero ``slot``'s caches: a new request must not start from the
+        recurrent state or KV entries its slot's previous tenant left."""
+        self.caches = self._reset(self.caches,
+                                  jnp.asarray(slot, dtype=jnp.int32))
 
     # -------------------------------------------------------------- slots --
     def free_slots(self) -> List[int]:
@@ -140,25 +183,29 @@ class ServeEngine:
     def add_request(self, req: Request) -> bool:
         """Admit a request into a free slot; prefill via decode replay.
 
-        The *blocking* prefill hook: the whole prompt is replayed through
-        the fused step before this returns, so every other lane stalls
-        for ``len(prompt)`` steps — exactly the pre-refactor behavior the
-        FIFO baseline preserves.
+        The *blocking* prefill hook: every prompt token but the last is
+        replayed through the fused step before this returns, so every
+        other lane stalls for ``len(prompt) - 1`` steps — the FIFO
+        baseline's behavior.  The last prompt token is the slot's first
+        decode input: its logits choose the first output token.  The
+        replay feeds token 0 to the other lanes, so it is correct for
+        one request at a time only.
         """
         free = self.free_slots()
         if not free:
             return False
         slot = free[0]
         t0 = time.perf_counter()
+        self._admit(slot)
         # single-slot prefill: replay prompt tokens through decode_step
         # (keeps one compiled step; a bulk prefill kernel is lowered for the
         # dry-run separately)
-        for i, tok in enumerate(req.prompt):
+        for i, tok in enumerate(req.prompt[:-1]):
             token = jnp.zeros((self.slots, 1), dtype=jnp.int32
                               ).at[slot, 0].set(int(tok))
             _, self.caches = self._decode(self.params, self.caches, token,
                                           jnp.asarray(i, dtype=jnp.int32))
-        self.positions[slot] = len(req.prompt)
+        self.positions[slot] = len(req.prompt) - 1
         self.active[slot] = req
         # prefill_s is a wall-clock bill: the request is not admitted until
         # its cache writes land, so the clock must stop on a drained queue
@@ -172,8 +219,9 @@ class ServeEngine:
 
         The lane consumes one prompt token per :meth:`advance` call,
         riding along with the decode lanes in the same fused step; when
-        the last prompt token is consumed the slot transitions to decode.
-        Returns the slot used.
+        every prompt token but the last is consumed the slot transitions
+        to decode, whose first step feeds the last prompt token.  The
+        slot's caches are reset on admission.  Returns the slot used.
         """
         free = self.free_slots()
         if slot is None:
@@ -182,8 +230,13 @@ class ServeEngine:
             slot = free[0]
         elif slot not in free:
             raise ValueError(f"slot {slot} is not free")
-        self.prefilling[slot] = req
-        self.prefill_done[slot] = 0
+        self._admit(slot)
+        if len(req.prompt) > 1:
+            self.prefilling[slot] = req
+            self.prefill_done[slot] = 0
+        else:
+            self.positions[slot] = 0
+            self.active[slot] = req
         return slot
 
     # ------------------------------------------------------------- decode --
@@ -191,11 +244,12 @@ class ServeEngine:
         """ONE fused engine step: advance every decode and prefill lane.
 
         Decode lanes are fed their last token and append the argmax
-        output; prefill lanes consume their next prompt token (the slot
-        flips to decode once the prompt is exhausted, after which it
-        behaves exactly like a blocking-prefilled slot).  Returns the
-        requests that finished on this step.  With no prefill lanes this
-        is bit-identical to the pre-refactor ``step()``.
+        output (and, for a request with :attr:`Request.keep_logits`, a
+        reference to the step's logits); prefill lanes consume their next
+        prompt token (the slot flips to decode once only the last prompt
+        token is left, after which it behaves exactly like a
+        blocking-prefilled slot).  Returns the requests that finished on
+        this step.
         """
         if not self.active and not self.prefilling:
             return []
@@ -222,6 +276,8 @@ class ServeEngine:
             nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
             for slot, req in list(self.active.items()):
                 req.out_tokens.append(int(nxt[slot]))
+                if req.keep_logits:
+                    req.out_logits.append((logits, slot))
                 self.positions[slot] += 1
                 self.stats.tokens_out += 1
                 if len(req.out_tokens) >= req.max_new_tokens:
@@ -231,8 +287,8 @@ class ServeEngine:
         for slot in list(self.prefilling):
             self.prefill_done[slot] += 1
             req = self.prefilling[slot]
-            if self.prefill_done[slot] >= len(req.prompt):
-                self.positions[slot] = len(req.prompt)
+            if self.prefill_done[slot] >= len(req.prompt) - 1:
+                self.positions[slot] = len(req.prompt) - 1
                 del self.prefilling[slot]
                 del self.prefill_done[slot]
                 self.active[slot] = req
@@ -247,11 +303,6 @@ class ServeEngine:
         else:
             self.stats.prefill_s += dt
         return finished
-
-    def step(self) -> None:
-        """Advance every active slot one token (legacy decode hook —
-        :meth:`advance` restricted to the no-prefill-lane case)."""
-        self.advance()
 
     # ---------------------------------------------------------------- run --
     def run(self, requests: List[Request], *,

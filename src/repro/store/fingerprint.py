@@ -24,7 +24,7 @@ from __future__ import annotations
 import os
 import platform
 from dataclasses import asdict, dataclass, fields
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 #: fallback package version when importlib metadata is unavailable
 #: (running from a source tree via PYTHONPATH, not an installed wheel)
@@ -33,10 +33,10 @@ _FALLBACK_VERSION = "0.1.0"
 
 def repro_version() -> str:
     """The repro package version stamped into every store artifact."""
+    from importlib.metadata import PackageNotFoundError, version
     try:
-        from importlib.metadata import version
         return version("repro")
-    except Exception:
+    except PackageNotFoundError:
         return _FALLBACK_VERSION
 
 
@@ -56,31 +56,17 @@ def _cpu_model() -> str:
 
 def _library_versions() -> str:
     """The measurement-relevant library stack, one canonical string."""
+    import jax
     import numpy as np
-    parts = [f"numpy={np.__version__}"]
-    try:
-        import jax
-        parts.append(f"jax={jax.__version__}")
-    except Exception:
-        parts.append("jax=absent")
-    return ",".join(parts)
+    return f"numpy={np.__version__},jax={jax.__version__}"
 
 
-def _jax_backend() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:
-        return "absent"
-
-
-def _jax_device_kind() -> str:
-    try:
-        import jax
-        devices = jax.devices()
-        return devices[0].device_kind if devices else "none"
-    except Exception:
-        return "none"
+def _jax_device() -> Tuple[str, str]:
+    """(backend, device kind of device 0) the kernels dispatch to.  A
+    failed device query raises: a store keyed to a placeholder would
+    match any platform whose query failed the same way."""
+    import jax
+    return jax.default_backend(), jax.devices()[0].device_kind
 
 
 @dataclass(frozen=True)
@@ -119,11 +105,12 @@ def current_fingerprint(*, dtype: str = "float32",
     default; a store of float64 Pallas-kernel measurements would carry
     its own.
     """
+    backend, device_kind = _jax_device()
     return PlatformFingerprint(
         cpu=_cpu_model(),
         cores=os.cpu_count() or 1,
-        backend=_jax_backend(),
-        device_kind=_jax_device_kind(),
+        backend=backend,
+        device_kind=device_kind,
         libraries=_library_versions(),
         dtype=dtype,
         repro_version=repro_version(),

@@ -58,6 +58,7 @@ from ..kernels.flash_attention import (attn_grid_steps, attn_proxy_problem,
 from ..kernels.matmul import grid_steps as matmul_grid_steps
 from ..kernels.matmul import matmul, proxy_problem
 from ..kernels.matmul import vmem_bytes as matmul_vmem_bytes
+from ..kernels.ops import require_tpu
 from ..kernels.ssd import ssd, ssd_grid_steps, ssd_proxy_problem, ssd_vmem_bytes
 from .suite import MicroBenchmark, MicroBenchmarkKey, MicroBenchmarkSuite
 
@@ -256,24 +257,22 @@ class DeviceSuite:
     measurements land in ``suite.results`` with ordinary "measured"
     provenance and wall-clock cost accounting, so store persistence,
     warm starts and the ``measured == 0`` zero-fresh-measurement proof
-    work unchanged.  ``interpret=None`` auto-gates: interpret mode
-    everywhere except a real TPU backend (the CI smoke lane runs
-    interpret-only).  ``passes`` defaults to the suite's repetition
-    protocol; ``transfer_measure_fn`` injects a synthetic memcpy probe
-    (tests fit against known constants).
+    work unchanged.  Measurement runs the kernels on the TPU and refuses
+    any other backend unless ``interpret=True`` is passed (the tests and
+    the CI smoke lane do: interpret timings measure the interpreter, not
+    a device).  ``passes`` defaults to the suite's repetition protocol;
+    ``transfer_measure_fn`` injects a synthetic memcpy probe (tests fit
+    against known constants).
     """
 
     def __init__(self, suite: MicroBenchmarkSuite, *,
-                 interpret: Optional[bool] = None,
+                 interpret: bool = False,
                  vmem_limit: int = VMEM_LIMIT,
                  steps_per_dim: int = 2,
                  passes: Optional[int] = None,
                  transfer_measure_fn=None,
                  transfer_repetitions: int = 5,
                  sweep_fn=None):
-        if interpret is None:
-            import jax
-            interpret = jax.default_backend() != "tpu"
         self.suite = suite
         self.interpret = bool(interpret)
         self.vmem_limit = vmem_limit
@@ -344,6 +343,7 @@ class DeviceSuite:
         import jax
         import jax.numpy as jnp
 
+        require_tpu(self.interpret)
         kernel = DEVICE_KERNELS[kernel_name]
         t_start = time.perf_counter()
         rng = np.random.default_rng(self.suite.seed)
@@ -361,8 +361,8 @@ class DeviceSuite:
             runners.append((cfg, jax.jit(chain, donate_argnums=(0,)), ops))
 
         with warnings.catch_warnings():
-            # CPU/interpret backends warn that donated buffers went
-            # unused — expected off-accelerator, not actionable here
+            # interpret mode warns that donated buffers went unused —
+            # expected off-accelerator, not actionable here
             warnings.filterwarnings("ignore", message=".*[Dd]onat")
             token = jnp.float32(0.0)
             firsts = {}
@@ -401,6 +401,8 @@ class DeviceSuite:
         memcpy probe's wall-clock lands in ``suite.cost_seconds``), or
         loaded from a store's ``__device__`` model set."""
         if self._transfer is None:
+            if self.transfer_measure_fn is None:
+                require_tpu(self.interpret)
             h2d, d2h, cost = measure_transfers(
                 measure_fn=self.transfer_measure_fn,
                 repetitions=self.transfer_repetitions)

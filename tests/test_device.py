@@ -26,6 +26,7 @@ import pytest
 from repro.core.model import ModelSet
 from repro.core.sampler import Stats
 from repro.core.transfer import (D2H, H2D, fit_transfer, measure_transfers)
+from repro.perf.roofline import TARGET_KIND
 from repro.perf.tile_tuner import (TileChoice, _mxu_eff, predict_tile_time,
                                    rank_tiles, select_tiles)
 from repro.store import (DEVICE_MODEL_SET, ModelStore, PlatformFingerprint,
@@ -113,8 +114,9 @@ def test_sweep_dedup_and_cost_accounting():
 def test_real_interpret_sweep_measures_all_registered_kernels():
     """The actual device-resident loop, interpret mode, tiny configs."""
     suite = MicroBenchmarkSuite(repetitions=2)
-    ds = DeviceSuite(suite, passes=2, transfer_measure_fn=synthetic_xfer)
-    assert ds.interpret            # auto-gated off-accelerator
+    ds = DeviceSuite(suite, interpret=True, passes=2,
+                     transfer_measure_fn=synthetic_xfer)
+    assert ds.interpret            # interpret mode is asked for, not inferred
     for name, cfg in [("pallas_matmul", (8, 8, 8)),
                       ("flash_attention", (8, 8, 16)),
                       ("pallas_ssd", (8, 4, 4))]:
@@ -122,6 +124,21 @@ def test_real_interpret_sweep_measures_all_registered_kernels():
         assert mb.stats.med > 0 and mb.first > 0 and mb.seconds > 0
         assert mb.key.config == cfg
     assert suite.measured == 3
+
+
+def test_measurement_refuses_to_run_off_tpu_without_interpret():
+    """Off the chip neither the kernel sweep nor the memcpy probe falls
+    back to the CPU: timing the interpreter is not measuring a device."""
+    import jax
+    if jax.default_backend() == "tpu":
+        pytest.skip("the refusal is for backends without a TPU")
+    ds = DeviceSuite(MicroBenchmarkSuite(repetitions=2))
+    assert not ds.interpret
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        ds.measure_grid("pallas_matmul", [(8, 8, 8)])
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        ds.transfer_models()
+    assert ds.suite.measured == 0
 
 
 # -------------------------------------------------------------- ranking --
@@ -152,7 +169,8 @@ def test_select_tiles_measured_path_matches_analytic_candidates():
     sess.device_suite(sweep_fn=synthetic_sweep,
                       transfer_measure_fn=synthetic_xfer)
     measured = rank_tiles(64, 64, 64, session=sess, candidates=(8, 16))
-    analytic = rank_tiles(64, 64, 64, analytic=True, candidates=(8, 16))
+    analytic = rank_tiles(64, 64, 64, analytic=True, candidates=(8, 16),
+                          device_kind=TARGET_KIND)
     assert {(t.bm, t.bn, t.bk) for t in measured} == \
         {(t.bm, t.bn, t.bk) for t in analytic}
     choice = select_tiles(64, 64, 64, session=sess, candidates=(8, 16))
@@ -160,10 +178,12 @@ def test_select_tiles_measured_path_matches_analytic_candidates():
     assert choice.source in ("measured", "model")
     assert choice.t_compute > 0
     # the analytic oracle also backs select_tiles when no session exists
-    fallback = select_tiles(64, 64, 64, candidates=(8, 16))
+    fallback = select_tiles(64, 64, 64, candidates=(8, 16),
+                            device_kind=TARGET_KIND)
     assert fallback.source == "analytic"
     assert fallback.predicted_s == pytest.approx(predict_tile_time(
-        64, 64, 64, fallback.bm, fallback.bn, fallback.bk))
+        64, 64, 64, fallback.bm, fallback.bn, fallback.bk,
+        device_kind=TARGET_KIND))
     # session front-end reaches the same device ranking
     direct = sess.rank_device_tiles("pallas_matmul", (64, 64, 64),
                                     [(8, 8, 8), (16, 16, 16)])

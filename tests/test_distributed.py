@@ -18,7 +18,7 @@ from repro.train.optimizer import AdamW
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_local_mesh(1, 1)
 
 
 def test_param_specs_divisibility():
@@ -92,9 +92,32 @@ def test_sharded_train_step_runs():
     step = jax.jit(make_train_step(cfg, opt))
     batch = {"inputs": jnp.zeros((2, 32), jnp.int32),
              "labels": jnp.zeros((2, 32), jnp.int32)}
-    with mesh:
+    with jax.set_mesh(mesh):
         p2, o2, loss = step(params, opt_state, batch)
     assert np.isfinite(float(loss))
+
+
+def test_embed_gather_under_explicit_mesh():
+    """With explicit mesh axes the vocab-sharded embedding gather needs
+    an output sharding; the rows follow the tokens' batch sharding."""
+    from jax.sharding import AxisType
+    from repro.models.transformer import _embed
+
+    cfg = reduced(get_config("deepseek-7b"), n_layers=2, d_model=64,
+                  d_ff=128, vocab=256)
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Explicit,) * 2)
+    tokens = jnp.arange(64, dtype=jnp.int32).reshape(2, 32)
+    with jax.set_mesh(mesh):
+        table = jax.device_put(params["embed"],
+                               NamedSharding(mesh, P("model", None)))
+        toks = jax.device_put(tokens, NamedSharding(mesh, P("data", None)))
+        rows = jax.jit(lambda t, i: _embed(cfg, {"embed": t}, i))(table,
+                                                                  toks)
+    assert jax.typeof(rows).sharding.spec == P("data", None, None)
+    np.testing.assert_array_equal(np.asarray(rows),
+                                  np.asarray(params["embed"])[tokens])
 
 
 def test_elastic_reshard_roundtrip(tmp_path):

@@ -84,6 +84,21 @@ def test_xla_fallbacks_match():
     from repro.kernels import ops
     x, y = _arr((128, 64), jnp.float32), _arr((64, 128), jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(ops.matmul(x, y, bm=64, bn=64, bk=64)),
+        np.asarray(ops.matmul(x, y, bm=64, bn=64, bk=64, interpret=True)),
         np.asarray(ops.matmul(x, y, use_pallas=False)),
         rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("call", ["matmul", "attention", "ssd"])
+def test_ops_refuse_to_run_off_tpu_without_interpret(call):
+    """Off the TPU a Pallas kernel runs only in interpret mode, and only
+    when asked for: no silent fallback."""
+    from repro.kernels import ops
+    if jax.default_backend() == "tpu":
+        pytest.skip("the refusal is for backends without a TPU")
+    x = _arr((8, 8), jnp.float32)
+    args = {"matmul": (x, x), "attention": (x[None, None],) * 3,
+            "ssd": (x[None, :, None], x[None, :, :1], x[0, :1], x[None, :, None],
+                    x[None, :, None])}[call]
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        getattr(ops, call)(*args)

@@ -1,11 +1,12 @@
 """Perf-layer tests: HLO collective parsing, trip-count scaling, roofline
 terms, tile tuner, analytic cost model."""
 
+import jax
 import pytest
 
 from repro.perf.analytic import cell_cost, forward_flops
 from repro.perf.hlo_scale import scaled_collective_bytes, split_computations
-from repro.perf.roofline import (RooflineTerms, collective_bytes,
+from repro.perf.roofline import (TARGET_KIND, RooflineTerms, collective_bytes,
                                  model_flops_for)
 from repro.perf.tile_tuner import predict_tile_time, select_tiles
 from repro.configs import SHAPES, get_config
@@ -52,14 +53,31 @@ def test_scaled_collectives_multiply_by_trip_count():
 def test_roofline_terms_dominance():
     t = RooflineTerms(flops=197e12, bytes_accessed=0.0,
                       coll_bytes={"all-reduce": 0}, n_devices=1,
-                      model_flops=197e12)
+                      model_flops=197e12, device_kind=TARGET_KIND)
     assert t.dominant == "compute"
     assert t.compute_s == pytest.approx(1.0)
     assert t.roofline_fraction == pytest.approx(1.0)
     t2 = RooflineTerms(flops=1.0, bytes_accessed=819e9,
-                       coll_bytes={"all-reduce": 0}, n_devices=1)
+                       coll_bytes={"all-reduce": 0}, n_devices=1,
+                       device_kind=TARGET_KIND)
     assert t2.dominant == "memory"
     assert t2.memory_s == pytest.approx(1.0)
+
+
+def test_peaks_table_refuses_unknown_device_kind():
+    from repro.perf.roofline import PEAKS, peaks
+    assert peaks("TPU v5 lite").flops == 197e12
+    assert all(p.source for p in PEAKS.values())
+    t = RooflineTerms(flops=1.0, bytes_accessed=1.0, coll_bytes={},
+                      n_devices=1, device_kind="cpu")
+    with pytest.raises(KeyError, match="no published peaks"):
+        t.compute_s
+    with pytest.raises(KeyError, match="no published peaks"):
+        predict_tile_time(256, 256, 256, 128, 128, 128, device_kind="cpu")
+    # the analytic ranking defaults to the attached device: a CPU here
+    assert jax.devices()[0].device_kind not in PEAKS
+    with pytest.raises(KeyError, match="no published peaks"):
+        select_tiles(256, 256, 256)
 
 
 def test_model_flops_factors():
@@ -95,21 +113,23 @@ def test_cell_cost_kinds():
 
 
 def test_tile_tuner_selects_legal_aligned():
-    c = select_tiles(4096, 4096, 4096)
+    c = select_tiles(4096, 4096, 4096, device_kind=TARGET_KIND)
     assert c.bm % 128 == 0 and c.bn % 128 == 0 and c.bk % 128 == 0
     # small matrices: clamped tiles
-    c2 = select_tiles(64, 64, 64, candidates=(64, 128))
+    c2 = select_tiles(64, 64, 64, candidates=(64, 128),
+                      device_kind=TARGET_KIND)
     assert (c2.bm, c2.bn, c2.bk) == (64, 64, 64)
 
 
 def test_tile_tuner_selection_is_argmin_and_vmem_safe():
     from repro.kernels.matmul import vmem_bytes
 
-    choice = select_tiles(4096, 4096, 4096)
+    choice = select_tiles(4096, 4096, 4096, device_kind=TARGET_KIND)
     # selected tile fits VMEM and beats (or ties) other legal candidates
     assert vmem_bytes(choice.bm, choice.bn, choice.bk) <= 16 * 2 ** 20
     for cand in ((128, 128, 128), (256, 256, 128), (512, 128, 128)):
-        t = predict_tile_time(4096, 4096, 4096, *cand)
+        t = predict_tile_time(4096, 4096, 4096, *cand,
+                              device_kind=TARGET_KIND)
         assert choice.predicted_s <= t * (1 + 1e-9)
 
 

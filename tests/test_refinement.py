@@ -159,7 +159,25 @@ def test_known_points_do_not_consume_budget():
     pieces = refine(dom, fn, [(1,)], config, known=known)
     # the root grid came for free, so the budget still allows splitting
     assert len(pieces) > 1
-    assert 0 < sum(counts.values()) <= config.max_points + len(grid)
+    assert 0 < sum(counts.values()) <= config.max_points
+
+
+@pytest.mark.parametrize("budget", [4, 5, 7, 10])
+def test_max_points_budget_is_a_hard_bound(budget):
+    """Fresh points never exceed the budget once the root grid is in, and
+    the pieces still tile the whole domain: a half whose grid would
+    overrun the budget keeps its parent's fit."""
+    dom = Domain((32,), (256,))
+    free_fn, free_counts = counting_sample_fn(kinked)
+    refine(dom, free_fn, [(1,)], CHEAP)
+    fn, counts = counting_sample_fn(kinked)
+    config = GeneratorConfig(**{**CHEAP.__dict__, "max_points": budget})
+    pieces = refine(dom, fn, [(1,)], config)
+    # the budget binds: unbounded refinement samples more
+    assert sum(counts.values()) <= budget < sum(free_counts.values())
+    covered = sorted((p.domain.lo[0], p.domain.hi[0]) for p in pieces)
+    assert covered[0][0] == 32 and covered[-1][1] == 256
+    assert all(a[1] <= b[0] + 8 for a, b in zip(covered, covered[1:]))
 
 
 # ---------------------------------------------------------- determinism ----

@@ -45,7 +45,7 @@ from ._jit import collect_jit_sites, is_jit_decorated
 #: the measurement, so its one sync is pragma-justified in place)
 HOT_PATHS: Mapping[str, Set[str]] = {
     "src/repro/serve/engine.py": {
-        "ServeEngine.advance", "ServeEngine.step", "ServeEngine.add_request",
+        "ServeEngine.advance", "ServeEngine.add_request",
     },
     "src/repro/serve/scheduler.py": {
         "serve_loop", "FifoScheduler.plan", "ModelGuidedScheduler.plan",
