@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device,
+in percent, while the blocked algorithms ran."""
+
+
+def read(run):
+    return None if run.reduced is None else 100.0 * run.reduced.idle_share
