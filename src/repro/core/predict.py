@@ -22,6 +22,14 @@ from .sampler import STATS, Stats
 _STD = STATS.index("std")
 
 
+def _span(name: str, **stats):
+    """A ``jax.profiler`` span carrying ``stats``, recorded only while a
+    trace is; jax is imported on first use, so this module loads without
+    it."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **stats)
+
+
 @dataclass(frozen=True)
 class KernelCall:
     """One kernel invocation inside an algorithm's call sequence."""
@@ -158,23 +166,27 @@ def compile_calls(calls_per_config: Sequence[Iterable[KernelCall]], *,
     :class:`FusedBatch` tensors the fused prediction path consumes.
     ``pad_rows_to`` forces the row axis to at least that width — results
     are bit-identical under any padding (padding rows scatter into a
-    dropped segment), which the property tests pin.
+    dropped segment), which the property tests pin.  The grouping is one
+    ``predict.compile`` profiler span (stat ``configs``).
     """
     seqs = list(calls_per_config)
-    buckets: Dict[Tuple[str, Tuple], Tuple[list, list]] = {}
-    for i, calls in enumerate(seqs):
-        for call in calls:
-            szs, cfg = buckets.setdefault((call.kernel, call.case), ([], []))
-            szs.append(call.sizes)
-            cfg.append(i)
-    groups = tuple(
-        CallGroup(kernel=kernel, case=case,
-                  sizes=np.asarray(szs, dtype=np.float64),
-                  config=np.asarray(cfg, dtype=np.intp))
-        for (kernel, case), (szs, cfg) in buckets.items()
-    )
-    return CompiledCalls(n_configs=len(seqs), groups=groups,
-                         fused=_fuse_batch(groups, len(seqs), pad_rows_to))
+    with _span("predict.compile", configs=len(seqs)):
+        buckets: Dict[Tuple[str, Tuple], Tuple[list, list]] = {}
+        for i, calls in enumerate(seqs):
+            for call in calls:
+                szs, cfg = buckets.setdefault((call.kernel, call.case),
+                                              ([], []))
+                szs.append(call.sizes)
+                cfg.append(i)
+        groups = tuple(
+            CallGroup(kernel=kernel, case=case,
+                      sizes=np.asarray(szs, dtype=np.float64),
+                      config=np.asarray(cfg, dtype=np.intp))
+            for (kernel, case), (szs, cfg) in buckets.items()
+        )
+        return CompiledCalls(n_configs=len(seqs), groups=groups,
+                             fused=_fuse_batch(groups, len(seqs),
+                                               pad_rows_to))
 
 
 Tracer = Callable[[int, int], List[KernelCall]]
@@ -204,12 +216,15 @@ class TraceCache:
         self.misses = 0
 
     def calls(self, tracer: Tracer, n: int, b: int) -> Tuple[KernelCall, ...]:
-        """The (cached) call sequence of one traced configuration."""
+        """The (cached) call sequence of one traced configuration; each
+        miss traces it in one ``predict.trace`` profiler span (stats
+        ``n``, ``b``)."""
         key = (tracer, n, b)
         out = self._calls.get(key)
         if out is None:
             self.misses += 1
-            out = tuple(tracer(n, b))
+            with _span("predict.trace", n=n, b=b):
+                out = tuple(tracer(n, b))
             self._calls[key] = out
         else:
             self.hits += 1
@@ -407,24 +422,27 @@ class PredictionEngine:
         batch's :class:`FusedBatch` tensors; on ``"numpy"`` groups are
         evaluated batch-wise and accumulated with a single ``np.add.at``
         over the precomputed ``flat_config`` scatter indices.  Either
-        way there is no per-group Python accumulation loop.
+        way there is no per-group Python accumulation loop.  The call is
+        one ``predict.evaluate`` profiler span (stat ``backend``).
         """
-        if not compiled.groups:
-            return np.zeros((compiled.n_configs, len(STATS)),
-                            dtype=np.float64)
-        fused = compiled.fused_batch()
-        if self.backend == "jax":
-            return _fused_predict_jax(self._fused_device_inputs(compiled),
-                                      compiled.n_configs)
-        est = np.concatenate(
-            [np.asarray(self.models[g.kernel].estimate_batch(g.case,
-                                                             g.sizes))
-             for g in compiled.groups], axis=0)
-        est[:, _STD] **= 2
-        acc = np.zeros((compiled.n_configs, len(STATS)), dtype=np.float64)
-        np.add.at(acc, fused.flat_config, est)
-        acc[:, _STD] = np.sqrt(acc[:, _STD])
-        return acc
+        with _span("predict.evaluate", backend=self.backend):
+            if not compiled.groups:
+                return np.zeros((compiled.n_configs, len(STATS)),
+                                dtype=np.float64)
+            fused = compiled.fused_batch()
+            if self.backend == "jax":
+                return _fused_predict_jax(
+                    self._fused_device_inputs(compiled), compiled.n_configs)
+            est = np.concatenate(
+                [np.asarray(self.models[g.kernel].estimate_batch(g.case,
+                                                                 g.sizes))
+                 for g in compiled.groups], axis=0)
+            est[:, _STD] **= 2
+            acc = np.zeros((compiled.n_configs, len(STATS)),
+                           dtype=np.float64)
+            np.add.at(acc, fused.flat_config, est)
+            acc[:, _STD] = np.sqrt(acc[:, _STD])
+            return acc
 
     def predict_compiled_grouped(self, compiled: CompiledCalls) -> np.ndarray:
         """The per-group reference path (PR-2 semantics), kept as the

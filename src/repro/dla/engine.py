@@ -21,6 +21,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.predict import CompiledCalls, KernelCall, compile_calls
 from . import kernels as K
@@ -189,7 +190,17 @@ def compile_traces(fns: Sequence[Callable[["Engine"], None]],
 
 
 class ExecEngine(Engine):
-    """Executes blocked algorithms on numpy-backed storage via JAX kernels."""
+    """Executes blocked algorithms on numpy-backed storage via JAX kernels.
+
+    Every kernel call passes through four profiler spans, in order:
+    ``dla.exec.upload`` (operands to the device, numpy's contiguous copy
+    of each view included), ``dla.exec.kernel`` (the host's dispatch),
+    ``dla.exec.download`` (waits for the result and copies it to the
+    host) and ``dla.exec.writeback`` (into the stored matrix); ``bind``
+    is one ``dla.exec.bind`` span.  Each span carries the ``kernel`` it
+    serves and, where data moves, its ``bytes``.  The spans are recorded
+    only while a ``jax.profiler`` trace is, and add no synchronization.
+    """
 
     def __init__(self, mats: Optional[Dict[str, np.ndarray]] = None):
         self.mats: Dict[str, np.ndarray] = dict(mats or {})
@@ -198,101 +209,111 @@ class ExecEngine(Engine):
 
     # -------------------------------------------------------------- store --
     def bind(self, key: str, array: np.ndarray) -> Matrix:
-        arr = np.array(array, dtype=np.float32, copy=True)
+        with TraceAnnotation("dla.exec.bind", bytes=4 * np.size(array)):
+            arr = np.array(array, dtype=np.float32, copy=True)
         self.mats[key] = arr
         return Matrix(key, arr.shape[0], arr.shape[1])
 
     def get(self, v: View) -> np.ndarray:
         return self.mats[v.mat][v.r0:v.r1, v.c0:v.c1]
 
-    def put(self, v: View, value) -> None:
-        self.mats[v.mat][v.r0:v.r1, v.c0:v.c1] = np.asarray(value)
+    def put(self, v: View, value, kernel: str) -> None:
+        """Copy a kernel's device result into the view ``v``."""
+        self._writeback(v, self._download(kernel, value), kernel)
+
+    def _download(self, kernel: str, value) -> np.ndarray:
+        with TraceAnnotation("dla.exec.download", kernel=kernel,
+                             bytes=value.nbytes):
+            return np.asarray(value)
+
+    def _writeback(self, v: View, host: np.ndarray, kernel: str) -> None:
+        with TraceAnnotation("dla.exec.writeback", kernel=kernel):
+            self.mats[v.mat][v.r0:v.r1, v.c0:v.c1] = host
 
     @staticmethod
     def _skip(*views: View) -> bool:
         return any(0 in v.shape for v in views)
 
     def _run(self, name: str, case: Tuple, *ops: np.ndarray):
-        out = K.KERNELS[name].run(case, tuple(K.jnp.asarray(o) for o in ops))
-        return out
+        with TraceAnnotation("dla.exec.upload", kernel=name,
+                             bytes=sum(o.nbytes for o in ops)):
+            args = tuple(K.jnp.asarray(o) for o in ops)
+        with TraceAnnotation("dla.exec.kernel", kernel=name):
+            return K.KERNELS[name].run(case, args)
+
+    def _call(self, name: str, case: Tuple, out: View, *ops: View) -> None:
+        """Run kernel ``name`` on the views ``ops`` and store its result in
+        ``out``."""
+        self.put(out, self._run(name, case, *map(self.get, ops)), name)
 
     # ------------------------------------------------------------ level 3 --
     def gemm(self, tA, tB, a, b, A, B, C):
         if self._skip(C) or (A.cols if tA == "N" else A.rows) == 0:
             return
-        out = self._run("gemm", (tA, tB, a, b),
-                        self.get(A), self.get(B), self.get(C))
-        self.put(C, out)
+        self._call("gemm", (tA, tB, a, b), C, A, B, C)
 
     def syrk(self, uplo, trans, a, b, A, C):
         if self._skip(C) or (A.cols if trans == "N" else A.rows) == 0:
             return
-        self.put(C, self._run("syrk", (uplo, trans, a, b),
-                              self.get(A), self.get(C)))
+        self._call("syrk", (uplo, trans, a, b), C, A, C)
 
     def syr2k(self, uplo, trans, a, b, A, B, C):
         if self._skip(C) or (A.cols if trans == "N" else A.rows) == 0:
             return
-        self.put(C, self._run("syr2k", (uplo, trans, a, b),
-                              self.get(A), self.get(B), self.get(C)))
+        self._call("syr2k", (uplo, trans, a, b), C, A, B, C)
 
     def symm(self, side, uplo, a, b, A, B, C):
         if self._skip(C, A):
             return
-        self.put(C, self._run("symm", (side, uplo, a, b),
-                              self.get(A), self.get(B), self.get(C)))
+        self._call("symm", (side, uplo, a, b), C, A, B, C)
 
     def trsm(self, side, uplo, tA, diag, a, A, B):
         if self._skip(B):
             return
-        self.put(B, self._run("trsm", (side, uplo, tA, diag, a),
-                              self.get(A), self.get(B)))
+        self._call("trsm", (side, uplo, tA, diag, a), B, A, B)
 
     def trmm(self, side, uplo, tA, diag, a, A, B):
         if self._skip(B):
             return
-        self.put(B, self._run("trmm", (side, uplo, tA, diag, a),
-                              self.get(A), self.get(B)))
+        self._call("trmm", (side, uplo, tA, diag, a), B, A, B)
 
     # -------------------------------------------------- unblocked kernels --
     def potf2(self, uplo, A):
         if self._skip(A):
             return
-        self.put(A, self._run("potf2", (uplo,), self.get(A)))
+        self._call("potf2", (uplo,), A, A)
 
     def trti2(self, uplo, diag, A):
         if self._skip(A):
             return
-        self.put(A, self._run("trti2", (uplo, diag), self.get(A)))
+        self._call("trti2", (uplo, diag), A, A)
 
     def lauu2(self, uplo, A):
         if self._skip(A):
             return
-        self.put(A, self._run("lauu2", (uplo,), self.get(A)))
+        self._call("lauu2", (uplo,), A, A)
 
     def sygs2(self, itype, uplo, A, L):
         if self._skip(A):
             return
-        self.put(A, self._run("sygs2", (itype, uplo),
-                              self.get(A), self.get(L)))
+        self._call("sygs2", (itype, uplo), A, A, L)
 
     def getf2(self, A):
         if self._skip(A):
             return
-        self.put(A, self._run("getf2", ("NP",), self.get(A)))
+        self._call("getf2", ("NP",), A, A)
 
     def geqr2(self, A):
         if self._skip(A):
             return
         q, r = self._run("geqr2", ("N",), self.get(A))
-        self.q_panels[(A.mat, A.r0, A.c0)] = np.asarray(q)
+        self.q_panels[(A.mat, A.r0, A.c0)] = self._download("geqr2", q)
         m, nb = A.shape
         out = np.zeros((m, nb), dtype=np.float32)
-        out[:nb, :nb] = np.triu(np.asarray(r))
-        self.put(A, out)
+        out[:nb, :nb] = np.triu(self._download("geqr2", r))
+        self._writeback(A, out, "geqr2")
 
     def trsyl(self, tA, tB, sgn, A, B, C):
         if self._skip(C):
             return
-        self.put(C, self._run("trsyl", (tA, tB, sgn),
-                              self.get(A), self.get(B), self.get(C)))
+        self._call("trsyl", (tA, tB, sgn), C, A, B, C)

@@ -61,6 +61,7 @@ def _gemm_fn(transA: str, transB: str, alpha: float, beta: float):
         a = A.T if transA == "T" else A
         b = B.T if transB == "T" else B
         return beta * C + alpha * (a @ b)
+    f.__name__ = f.__qualname__ = "gemm"
     return jax.jit(f)
 
 
@@ -70,6 +71,7 @@ def _syrk_fn(uplo: str, trans: str, alpha: float, beta: float):
     def f(A, C):
         aat = A @ A.T if trans == "N" else A.T @ A
         return beta * C + alpha * aat
+    f.__name__ = f.__qualname__ = "syrk"
     return jax.jit(f)
 
 
@@ -81,6 +83,7 @@ def _syr2k_fn(uplo: str, trans: str, alpha: float, beta: float):
         else:
             upd = A.T @ B + B.T @ A
         return beta * C + alpha * upd
+    f.__name__ = f.__qualname__ = "syr2k"
     return jax.jit(f)
 
 
@@ -91,6 +94,7 @@ def _symm_fn(side: str, uplo: str, alpha: float, beta: float):
             jnp.triu(A) + jnp.triu(A, 1).T
         prod = sym @ B if side == "L" else B @ sym
         return beta * C + alpha * prod
+    f.__name__ = f.__qualname__ = "symm"
     return jax.jit(f)
 
 
@@ -104,6 +108,7 @@ def _trsm_fn(side: str, uplo: str, transA: str, diag: str, alpha: float):
             transpose_a=(transA == "T"),
             unit_diagonal=(diag == "U"),
         )
+    f.__name__ = f.__qualname__ = "trsm"
     return jax.jit(f)
 
 
@@ -116,6 +121,7 @@ def _trmm_fn(side: str, uplo: str, transA: str, diag: str, alpha: float):
                                                           dtype=tri.dtype)
         t = tri.T if transA == "T" else tri
         return alpha * (t @ B) if side == "L" else alpha * (B @ t)
+    f.__name__ = f.__qualname__ = "trmm"
     return jax.jit(f)
 
 
