@@ -31,18 +31,22 @@ def _exec_fns(n: int):
     def run_potrf():
         eng = ExecEngine()
         blocked.potrf(eng, eng.bind("A", A_spd), n, BLOCK, variant=3)
+        return eng.mats["A"]
 
     def run_trtri():
         eng = ExecEngine()
         blocked.trtri(eng, eng.bind("A", A_low), n, BLOCK, variant=3)
+        return eng.mats["A"]
 
     def run_lauum():
         eng = ExecEngine()
         blocked.lauum(eng, eng.bind("A", A_low), n, BLOCK)
+        return eng.mats["A"]
 
     def run_getrf():
         eng = ExecEngine()
         blocked.getrf(eng, eng.bind("A", A_gen), n, BLOCK)
+        return eng.mats["A"]
 
     return {"potrf3": run_potrf, "trtri3": run_trtri, "lauum": run_lauum,
             "getrf": run_getrf}

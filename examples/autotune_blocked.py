@@ -56,6 +56,7 @@ def main():
         def run(v=v):
             eng = ExecEngine()
             blocked.potrf(eng, eng.bind("A", A0), n, 48, variant=v)
+            return eng.mats["A"]
         meas[f"potrf{v}"] = median_time(run, 5)
         print(f"   potrf{v}: measured {meas[f'potrf{v}'] * 1e3:7.2f} ms")
     meas_best = min(meas, key=meas.get)
@@ -73,6 +74,7 @@ def main():
         def run(b=b):
             eng = ExecEngine()
             blocked.potrf(eng, eng.bind("A", A0), n, b, variant=variant)
+            return eng.mats["A"]
         meas_profile[b] = median_time(run, 5)
     b_opt = min(meas_profile, key=meas_profile.get)
     yld = meas_profile[b_opt] / meas_profile[b_pred]

@@ -48,7 +48,9 @@ class Stats:
         n = len(xs)
         if n == 0:
             raise ValueError("no samples")
-        mean = sum(xs) / n
+        # the sum's rounding can put the mean of equal samples one unit
+        # below them: a mean lies between the least and the greatest
+        mean = min(max(sum(xs) / n, xs[0]), xs[-1])
         var = sum((x - mean) ** 2 for x in xs) / n
         med = xs[n // 2] if n % 2 else 0.5 * (xs[n // 2 - 1] + xs[n // 2])
         return Stats(min=xs[0], med=med, max=xs[-1], mean=mean,

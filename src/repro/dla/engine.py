@@ -3,12 +3,12 @@
 A blocked algorithm is written ONCE against the :class:`Engine` interface and
 then either
 
-* **executed** (:class:`ExecEngine`) — sub-matrix views of numpy-backed
-  storage are extracted, pushed through the jitted JAX kernels of
-  ``repro.dla.kernels`` and written back (the "LAPACK calling BLAS"
-  structure; host round-trips are part of the call, and the model generator
-  times kernels the same way so predictions and executions see identical
-  per-call overhead), or
+* **executed** (:class:`ExecEngine`) — the matrices stay on the device,
+  and each kernel call runs a jitted JAX kernel of ``repro.dla.kernels``
+  on sub-matrix views of them and updates the stored matrix in place (the
+  "LAPACK calling BLAS" structure; the model generator times kernels the
+  same way, operands on the device and no transfer in the call, so
+  predictions and executions see the same per-call cost), or
 * **traced** (:class:`TraceEngine`) — only the ``(kernel, case, sizes)``
   sequence is recorded, *without any execution*.  This is what the paper's
   predictions consume (§4.1): the call sequence is fully determined by the
@@ -17,10 +17,15 @@ then either
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+import functools
+from collections.abc import Mapping
+from dataclasses import dataclass
+from typing import Callable, Dict, Iterator, List, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.profiler import TraceAnnotation
 
 from ..core.predict import CompiledCalls, KernelCall, compile_calls
@@ -189,62 +194,158 @@ def compile_traces(fns: Sequence[Callable[["Engine"], None]],
     return compile_calls([trace_calls(fn) for fn in fns])
 
 
-class ExecEngine(Engine):
-    """Executes blocked algorithms on numpy-backed storage via JAX kernels.
+@functools.lru_cache(maxsize=None)
+def _program(name: str):
+    """Kernel ``name`` as one jitted program over stored matrices.
 
-    Every kernel call passes through four profiler spans, in order:
-    ``dla.exec.upload`` (operands to the device, numpy's contiguous copy
-    of each view included), ``dla.exec.kernel`` (the host's dispatch),
-    ``dla.exec.download`` (waits for the result and copies it to the
-    host) and ``dla.exec.writeback`` (into the stored matrix); ``bind``
-    is one ``dla.exec.bind`` span.  Each span carries the ``kernel`` it
-    serves and, where data moves, its ``bytes``.  The spans are recorded
-    only while a ``jax.profiler`` trace is, and add no synchronization.
+    ``program(case, views, out, written, read, offsets)`` slices operand
+    ``i`` of shape ``views[i][1]`` out of matrix ``views[i][0]`` of
+    ``(written,) + read`` at ``offsets[2i:2i+2]``, runs the kernel, and
+    writes its result into ``written`` at operand ``out``'s offsets.
+    ``written`` is donated, so XLA updates it in place.  Returns the
+    updated matrix and the kernel's extra outputs (geqr2's ``q``).
+    """
+    kernel = K.KERNELS[name]
+
+    def program(case, views, out, written, read, offsets):
+        mats = (written,) + read
+        ops = tuple(lax.dynamic_slice(mats[m], (offsets[2 * i],
+                                                offsets[2 * i + 1]), shape)
+                    for i, (m, shape) in enumerate(views))
+        result, extra = kernel.run(case, ops), ()
+        if name == "geqr2":     # store R over the panel, hand Q back
+            q, r = result
+            result, extra = jnp.zeros_like(q).at[:r.shape[0]].set(
+                jnp.triu(r)), (q,)
+        at = (offsets[2 * out], offsets[2 * out + 1])
+        return lax.dynamic_update_slice(written, result, at), extra
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, static_argnames=("case", "views", "out"),
+                   donate_argnames=("written",))
+
+
+@functools.lru_cache(maxsize=1 << 14)
+def _device_offsets(offsets: Tuple[int, ...]):
+    """A kernel call's view offsets as an int32 device array, uploaded
+    once per distinct tuple: a blocked algorithm repeats its offsets from
+    problem to problem, so a call moves nothing to the device."""
+    return jax.device_put(np.array(offsets, np.int32))
+
+
+def _download(value, kernel: str) -> np.ndarray:
+    """Device array ``value`` downloaded into a writable host array.
+
+    The array JAX downloads into is its own and only marked read-only
+    because JAX keeps it as the device array's cached value; the callers
+    drop the device array, so it is handed on writable instead of copied
+    (a copy into fresh pages costs more than the transfer).  Where the
+    download aliases memory numpy cannot write (JAX's CPU backend), it is
+    copied."""
+    with TraceAnnotation("dla.exec.download", kernel=kernel,
+                         bytes=value.nbytes):
+        host = np.asarray(value)
+        if not host.flags.writeable:
+            try:
+                host.flags.writeable = True
+            except ValueError:
+                host = host.copy()
+        return host
+
+
+def _upload(host: np.ndarray, kernel: str):
+    with TraceAnnotation("dla.exec.upload", kernel=kernel,
+                         bytes=host.nbytes):
+        return jax.device_put(host)
+
+
+class HostMatrices(Mapping):
+    """``ExecEngine.mats``: the stored matrices, each read as a host numpy
+    array.
+
+    A matrix lives on the device (``device``) until host code reads it:
+    ``mats[key]`` then waits for the device, downloads the matrix once and
+    keeps the host array (``host``).  From then on that array is the
+    matrix, so host code may read and write it in place; the next kernel
+    call that touches the matrix uploads it again (:meth:`on_device`) and
+    drops the host array."""
+
+    def __init__(self):
+        self.device: Dict[str, jax.Array] = {}
+        self.host: Dict[str, np.ndarray] = {}
+
+    def __getitem__(self, key: str) -> np.ndarray:
+        if key not in self.host:
+            self.host[key] = _download(self.device.pop(key), "fetch")
+        return self.host[key]
+
+    def __iter__(self) -> Iterator[str]:
+        return iter(list(self.device) + list(self.host))
+
+    def __len__(self) -> int:
+        return len(self.device) + len(self.host)
+
+    def on_device(self, key: str, kernel: str):
+        """Matrix ``key`` on the device, uploaded again for ``kernel``'s
+        call if host code holds it."""
+        host = self.host.pop(key, None)
+        if host is not None:
+            self.device[key] = _upload(host, kernel)
+        return self.device[key]
+
+
+class ExecEngine(Engine):
+    """Executes blocked algorithms via JAX kernels on matrices it keeps on
+    the device.
+
+    ``bind`` uploads a matrix once.  Each kernel call is one jitted
+    program (:func:`_program`) that slices its operands out of the stored
+    matrices, runs the kernel and writes the result into the matrix it
+    updates, in place: nothing moves between host and device between
+    calls.  ``mats`` (:class:`HostMatrices`) hands a matrix to the host
+    with one download, after the device has finished with it.
+
+    Profiler spans: ``dla.exec.bind`` around ``bind``, with a
+    ``dla.exec.upload`` (``kernel="bind"``) inside; a ``dla.exec.upload``
+    for each upload of a matrix that host code held; one
+    ``dla.exec.kernel`` (the host's dispatch) for each kernel call; a
+    ``dla.exec.download`` (``kernel="fetch"``) for each read of ``mats``
+    that downloads.  Uploads and downloads carry their ``bytes``.  The
+    spans are recorded only while a ``jax.profiler`` trace is, and add no
+    synchronization.
     """
 
-    def __init__(self, mats: Optional[Dict[str, np.ndarray]] = None):
-        self.mats: Dict[str, np.ndarray] = dict(mats or {})
+    def __init__(self):
+        self.mats = HostMatrices()
         # QR panels store reflector blocks out-of-place
         self.q_panels: Dict[Tuple, np.ndarray] = {}
 
     # -------------------------------------------------------------- store --
     def bind(self, key: str, array: np.ndarray) -> Matrix:
+        """Store ``array`` (as float32) under ``key``, on the device.  The
+        caller's array is read once and never written."""
         with TraceAnnotation("dla.exec.bind", bytes=4 * np.size(array)):
-            arr = np.array(array, dtype=np.float32, copy=True)
-        self.mats[key] = arr
-        return Matrix(key, arr.shape[0], arr.shape[1])
-
-    def get(self, v: View) -> np.ndarray:
-        return self.mats[v.mat][v.r0:v.r1, v.c0:v.c1]
-
-    def put(self, v: View, value, kernel: str) -> None:
-        """Copy a kernel's device result into the view ``v``."""
-        self._writeback(v, self._download(kernel, value), kernel)
-
-    def _download(self, kernel: str, value) -> np.ndarray:
-        with TraceAnnotation("dla.exec.download", kernel=kernel,
-                             bytes=value.nbytes):
-            return np.asarray(value)
-
-    def _writeback(self, v: View, host: np.ndarray, kernel: str) -> None:
-        with TraceAnnotation("dla.exec.writeback", kernel=kernel):
-            self.mats[v.mat][v.r0:v.r1, v.c0:v.c1] = host
+            self.mats.host.pop(key, None)
+            stored = _upload(np.asarray(array, dtype=np.float32), "bind")
+        self.mats.device[key] = stored
+        return Matrix(key, *stored.shape)
 
     @staticmethod
     def _skip(*views: View) -> bool:
         return any(0 in v.shape for v in views)
 
-    def _run(self, name: str, case: Tuple, *ops: np.ndarray):
-        with TraceAnnotation("dla.exec.upload", kernel=name,
-                             bytes=sum(o.nbytes for o in ops)):
-            args = tuple(K.jnp.asarray(o) for o in ops)
-        with TraceAnnotation("dla.exec.kernel", kernel=name):
-            return K.KERNELS[name].run(case, args)
-
-    def _call(self, name: str, case: Tuple, out: View, *ops: View) -> None:
+    def _call(self, name: str, case: Tuple, out: View, *ops: View) -> Tuple:
         """Run kernel ``name`` on the views ``ops`` and store its result in
-        ``out``."""
-        self.put(out, self._run(name, case, *map(self.get, ops)), name)
+        ``out`` (one of ``ops``); returns the kernel's extra outputs."""
+        keys = list(dict.fromkeys([out.mat] + [v.mat for v in ops]))
+        mats = [self.mats.on_device(k, name) for k in keys]
+        views = tuple((keys.index(v.mat), v.shape) for v in ops)
+        offsets = _device_offsets(tuple(x for v in ops for x in (v.r0, v.c0)))
+        with TraceAnnotation("dla.exec.kernel", kernel=name):
+            self.mats.device[out.mat], extra = _program(name)(
+                case, views, ops.index(out), mats[0],
+                tuple(mats[1:]), offsets)
+        return extra
 
     # ------------------------------------------------------------ level 3 --
     def gemm(self, tA, tB, a, b, A, B, C):
@@ -306,12 +407,8 @@ class ExecEngine(Engine):
     def geqr2(self, A):
         if self._skip(A):
             return
-        q, r = self._run("geqr2", ("N",), self.get(A))
-        self.q_panels[(A.mat, A.r0, A.c0)] = self._download("geqr2", q)
-        m, nb = A.shape
-        out = np.zeros((m, nb), dtype=np.float32)
-        out[:nb, :nb] = np.triu(self._download("geqr2", r))
-        self._writeback(A, out, "geqr2")
+        (q,) = self._call("geqr2", ("N",), A, A)
+        self.q_panels[(A.mat, A.r0, A.c0)] = _download(q, "geqr2")
 
     def trsyl(self, tA, tB, sgn, A, B, C):
         if self._skip(C):

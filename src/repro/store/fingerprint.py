@@ -26,6 +26,14 @@ import platform
 from dataclasses import asdict, dataclass, fields
 from typing import Dict, List, Tuple
 
+#: how the stored kernel timings call a kernel
+#: (``repro.dla.kernels.KernelDef.make_call``): operands already on the
+#: device, timed up to the end of the kernel's device work, nothing
+#: downloaded — the call ``repro.dla.engine.ExecEngine`` makes.  A store
+#: without it timed another call (a host round trip per kernel call) and
+#: refuses to load.
+KERNEL_CALL = "device-resident"
+
 #: fallback package version when importlib metadata is unavailable
 #: (running from a source tree via PYTHONPATH, not an installed wheel)
 _FALLBACK_VERSION = "0.1.0"
@@ -80,6 +88,7 @@ class PlatformFingerprint:
     libraries: str        # "numpy=...,jax=..." measurement library stack
     dtype: str            # operand dtype the micro-benchmarks run in
     repro_version: str    # repro package version that wrote the store
+    kernel_call: str = KERNEL_CALL  # how the kernel timings call a kernel
 
     def as_dict(self) -> Dict[str, object]:
         return asdict(self)

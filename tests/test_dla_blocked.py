@@ -121,9 +121,9 @@ def test_trace_matches_execution_structure():
             super().__init__()
             self.seq = []
 
-        def _run(self, name, case, *ops):
+        def _call(self, name, case, out, *ops):
             self.seq.append((name, tuple(case)))
-            return super()._run(name, case, *ops)
+            return super()._call(name, case, out, *ops)
 
     eng = RecordingExec()
     A = eng.bind("A", _spd(N))

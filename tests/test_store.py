@@ -149,6 +149,23 @@ def test_schema_bump_refuses_even_with_allow_mismatch(tmp_path):
         ModelStore.load(path, allow_mismatch=True)   # schema gap is final
 
 
+def test_store_of_round_trip_kernel_timings_refuses(tmp_path):
+    """A store written before the kernel-call convention was recorded
+    timed a host round trip per kernel call: its fingerprint lacks
+    ``kernel_call``, and it models another program."""
+    sess = fake_session()
+    sess.rank_contraction_algorithms(SPEC, SIZES)
+    path = tmp_path / "store.json"
+    sess.save_store(path)
+    payload = json.loads(path.read_text())
+    assert payload["fingerprint"]["kernel_call"] == "device-resident"
+    del payload["fingerprint"]["kernel_call"]
+    path.write_text(json.dumps(payload))
+    with pytest.raises(StoreMismatchError,
+                       match="kernel_call: stored='missing'"):
+        ModelStore.load(path)
+
+
 def test_session_store_and_suite_are_exclusive():
     sess = fake_session()
     sess.rank_contraction_algorithms(SPEC, SIZES)

@@ -24,10 +24,6 @@ from repro.dla.tracers import CHOLESKY_TRACERS, potrf_tracer
 pytestmark = pytest.mark.filterwarnings(
     "ignore:builtin type event_stats:DeprecationWarning")
 
-PHASES = ["dla.exec.upload", "dla.exec.kernel", "dla.exec.download",
-          "dla.exec.writeback"]
-
-
 def recorded_spans(log_dir, work):
     """Run ``work`` under the profiler; its ``dla.*`` and ``predict.*``
     spans as ``(start_ns, name, stats)``, in time order."""
@@ -52,53 +48,155 @@ def recorded_spans(log_dir, work):
     return sorted(spans, key=lambda sp: sp[0])
 
 
-def operand_bytes(call):
-    """(uploaded, downloaded) float32 bytes of one ``ExecEngine`` call of
-    the Cholesky kernels: every operand's full view up, the result down."""
-    s = call.sizes
-    if call.kernel == "gemm":
-        m, n, k = s
-        return 4 * (m * k + k * n + m * n), 4 * m * n
-    if call.kernel == "syrk":
-        n, k = s
-        return 4 * (n * k + n * n), 4 * n * n
-    if call.kernel == "trsm":
-        m, n = s
-        t = n if call.case[0] == "R" else m
-        return 4 * (t * t + m * n), 4 * m * n
-    if call.kernel == "potf2":
-        (n,) = s
-        return 4 * n * n, 4 * n * n
-    raise KeyError(call.kernel)
+def potrf_calls(n, b, variant):
+    """The non-degenerate kernel calls of one blocked Cholesky."""
+    return [c for c in trace_calls(lambda e: blocked.potrf(
+        e, Matrix("A", n, n), n, b, variant)) if min(c.sizes) > 0]
+
+
+def spd(n, seed):
+    g = np.random.default_rng(seed).standard_normal((n, n))
+    return g @ g.T + n * np.eye(n)
 
 
 @pytest.mark.parametrize("variant", [1, 2, 3])
 def test_exec_engine_spans_each_kernel_call(tmp_path, variant):
+    """bind uploads once, each kernel call is one dispatch with no
+    transfer, and the fetch downloads once: 4 n^2 bytes each way."""
     n, b = 256, 64
-    rng = np.random.default_rng(variant)
-    g = rng.standard_normal((n, n))
-    a = g @ g.T + n * np.eye(n)
+    a = spd(n, variant)
     eng = ExecEngine()
+    out = {}
 
     def work():
         blocked.potrf(eng, eng.bind("A", a), n, b, variant)
+        out["L"] = eng.mats["A"]
 
     spans = recorded_spans(tmp_path, work)
-    calls = [c for c in trace_calls(lambda e: blocked.potrf(
-        e, Matrix("A", n, n), n, b, variant)) if min(c.sizes) > 0]
+    calls = potrf_calls(n, b, variant)
     assert [name for _, name, _ in spans] == \
-        ["dla.exec.bind"] + PHASES * len(calls)
+        ["dla.exec.bind", "dla.exec.upload"] + \
+        ["dla.exec.kernel"] * len(calls) + ["dla.exec.download"]
     assert spans[0][2]["bytes"] == 4 * n * n
-    for phase in PHASES:
-        assert [st["kernel"] for _, name, st in spans if name == phase] == \
-            [c.kernel for c in calls]
-    up, down = (sum(x) for x in zip(*map(operand_bytes, calls)))
-    assert sum(st["bytes"] for _, name, st in spans
-               if name == "dla.exec.upload") == up
-    assert sum(st["bytes"] for _, name, st in spans
-               if name == "dla.exec.download") == down
+    assert spans[1][2] == {"kernel": "bind", "bytes": 4 * n * n}
+    assert [st["kernel"] for _, name, st in spans
+            if name == "dla.exec.kernel"] == [c.kernel for c in calls]
+    assert spans[-1][2] == {"kernel": "fetch", "bytes": 4 * n * n}
+    np.testing.assert_allclose(np.tril(out["L"]), np.linalg.cholesky(a),
+                               rtol=1e-3, atol=1e-3)
+
+
+def _lower(n, seed):
+    a = np.tril(np.random.default_rng(seed).standard_normal((n, n)))
+    np.fill_diagonal(a, np.abs(a.diagonal()) + n)
+    return a
+
+
+#: algorithm -> (matrices it binds, how it runs on them), n = 96, b = 32
+PRODUCT_PATHS = {
+    "potrf": (lambda: {"A": spd(96, 0)},
+              lambda e, m: blocked.potrf(e, m["A"], 96, 32, 3)),
+    "trtri": (lambda: {"A": _lower(96, 1)},
+              lambda e, m: blocked.trtri(e, m["A"], 96, 32, 5)),
+    "lauum": (lambda: {"A": _lower(96, 2)},
+              lambda e, m: blocked.lauum(e, m["A"], 96, 32)),
+    "getrf": (lambda: {"A": spd(96, 3)},
+              lambda e, m: blocked.getrf(e, m["A"], 96, 32)),
+    "sygst": (lambda: {"A": spd(96, 4), "L": _lower(96, 4)},
+              lambda e, m: blocked.sygst(e, m["A"], m["L"], 96, 32)),
+}
+
+
+@pytest.mark.parametrize("alg", sorted(PRODUCT_PATHS))
+def test_product_path_moves_each_matrix_once(tmp_path, alg):
+    """bind -> kernel calls -> one read of ``mats``: 4 n^2 bytes up per
+    bound matrix, 4 n^2 down for the one fetched, nothing per call."""
+    make, run = PRODUCT_PATHS[alg]
+    inputs = make()
+    eng = ExecEngine()
+
+    def work():
+        run(eng, {k: eng.bind(k, v) for k, v in inputs.items()})
+        eng.mats["A"]
+
+    spans = recorded_spans(tmp_path, work)
+    moved = [(name, st["kernel"], st["bytes"]) for _, name, st in spans
+             if name in ("dla.exec.upload", "dla.exec.download")]
+    assert moved == [("dla.exec.upload", "bind", 4 * 96 * 96)] * \
+        len(inputs) + [("dla.exec.download", "fetch", 4 * 96 * 96)]
+    assert sum(name == "dla.exec.kernel" for _, name, _ in spans) > 0
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_bind_never_writes_the_callers_array(dtype):
+    """The stored matrix is donated to every kernel call; the caller's
+    array, read once by ``bind``, stays as it was."""
+    n, b = 128, 32
+    a = spd(n, 5).astype(dtype)
+    before = a.copy()
+    eng = ExecEngine()
+    blocked.potrf(eng, eng.bind("A", a), n, b, 3)
     np.testing.assert_allclose(np.tril(eng.mats["A"]),
-                               np.linalg.cholesky(a), rtol=1e-3, atol=1e-3)
+                               np.linalg.cholesky(before), rtol=1e-3,
+                               atol=1e-3)
+    np.testing.assert_array_equal(a, before)
+    assert a.dtype == dtype
+
+
+def _scale_first_entry(host, b):
+    host[0, 0] *= 1.01            # fault injection into a finished block
+
+
+def _scale_panel(host, b):
+    host[b:, :b] = 2 * host[b:, :b]   # host panel work, as geqrf_exec
+
+
+@pytest.mark.parametrize("write", [_scale_first_entry, _scale_panel])
+def test_host_write_through_mats_reaches_the_next_kernel_call(tmp_path,
+                                                               write):
+    """Host code that writes ``mats[key]`` between two kernel calls hands
+    the next call the written values, through one upload."""
+    n, b = 128, 64
+    a = spd(n, 6)
+    eng = ExecEngine()
+    A = eng.bind("A", a)
+    L11 = A.v(0, b, 0, b)
+
+    def work():
+        eng.potf2("L", L11)
+        write(eng.mats["A"], b)
+        eng.trsm("R", "L", "T", "N", 1, L11, A.v(b, n, 0, b))
+
+    spans = recorded_spans(tmp_path, work)
+    assert [(name, st.get("kernel")) for _, name, st in spans] == [
+        ("dla.exec.kernel", "potf2"), ("dla.exec.download", "fetch"),
+        ("dla.exec.upload", "trsm"), ("dla.exec.kernel", "trsm")]
+    want = np.array(a)
+    want[:b, :b] = np.linalg.cholesky(a[:b, :b])
+    write(want, b)
+    want[b:, :b] = np.linalg.solve(np.tril(want[:b, :b]), want[b:, :b].T).T
+    got = eng.mats["A"]
+    np.testing.assert_allclose(np.tril(got[:, :b]), np.tril(want[:, :b]),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_engine_is_freed_without_the_cycle_collector():
+    """An engine and the matrices it holds are freed as soon as the
+    caller drops them, so a stream of problems reuses their memory."""
+    import gc
+    import weakref
+    n = 64
+    gc.disable()
+    try:
+        eng = ExecEngine()
+        blocked.potrf(eng, eng.bind("A", spd(n, 8)), n, 32, 3)
+        host = eng.mats["A"]
+        gone = weakref.ref(eng)
+        del eng
+        assert gone() is None
+        assert host.flags.writeable
+    finally:
+        gc.enable()
 
 
 def test_trace_cache_spans_each_miss_and_no_hit(tmp_path):
