@@ -24,16 +24,24 @@ import numpy as np
 
 #: served logits against the reference's, max over served tokens of
 #: max |served - ref| / max |ref| in the token's row.  Readings it was set
-#: from (PERF.md, "Limits"): the program's, up to 4.40e-4, and the
-#: control's, the reference at matmul precision ``high`` (three bfloat16
-#: passes), from 1.21e-3.
+#: from (TPU v5e, at the cell's sizes; PERF.md, section 2): the program's
+#: 9.2e-5 to 3.18e-4 over 47 seeds; the control's, the reference at
+#: matmul precision ``high`` (three bfloat16 passes) in the program's
+#: place, 1.56e-3 to 1.89e-3 on 12 seeds.  The limit lies 2.5 times above
+#: the one and 1.95 times below the other: fresh seeds read higher than
+#: those a limit is set from, and the control, which is what a later
+#: change to a lower precision would read, fails it.
 LOGIT_LIMIT = 8e-4
-#: which of ``kinds/serve.logit_numbers`` is ``logit_err``
+#: which of ``kinds/serve.logit_numbers`` is ``logit_err``: the worst row,
+#: since a fault in one slot's lane shows in its rows alone
 LOGIT_NUMBER = "row_max"
 #: the widest gap, in logits, by which a served token's reference logit
 #: lies below the reference's best at its position.  Readings it was set
-#: from (PERF.md, "Limits"): the program's, and a served token altered
-#: where it is produced.
+#: from (TPU v5e; PERF.md, section 2): the program's 0 on every seed (it
+#: serves the reference's greedy token everywhere), the control's 0 to
+#: 1.9e-3, a served token altered where it is produced (``controls.py
+#: --fault altered_token``) 7.44 and 7.58.  The control fails
+#: ``logit_err``, not this; the altered token fails this, not that.
 GAP_LIMIT = 0.05
 EPS = 1e-6
 

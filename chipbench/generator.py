@@ -15,9 +15,14 @@ arrives; the rest are parameters:
   lengths at evenly spaced quantiles of their clipped lognormal
   distributions (given by mean and sigma), paired by a fixed shuffle, and
   gaps at evenly spaced quantiles of the exponential, scaled to the
-  phase's length.  The seed draws the order of lengths and of gaps in
-  each phase, and the prompts' token ids.  So every seed sends the same
-  requests in each phase, in another order and at other times.
+  phase's length.  Requests and gaps are laid out in a fixed shuffle, the
+  same for every seed, and the seed orders them within each run of
+  ``shuffle_block`` consecutive arrivals; it also draws the prompts'
+  token ids.  So every seed sends the same requests in each phase, and
+  the same work in each stretch of ``shuffle_block`` arrivals, in another
+  order and at other times within it: a seed that put the long requests
+  at the window's close would count fewer of their tokens in it, and one
+  that bunched the arrivals would queue more of them.
 """
 
 from __future__ import annotations
@@ -73,6 +78,16 @@ class Arrival:
     phase: str                  # "ramp", "window" or "tail"
 
 
+def block_shuffle(count: int, block: int, rng: np.random.Generator,
+                  fixed: int) -> np.ndarray:
+    """A fixed shuffle of ``range(count)`` (from ``fixed``), with each run
+    of ``block`` consecutive entries put in an order drawn from ``rng``."""
+    out = np.random.default_rng(fixed).permutation(count)
+    for lo in range(0, count, block):
+        out[lo:lo + block] = rng.permutation(out[lo:lo + block])
+    return out
+
+
 def phase_seconds(mix: Mapping[str, Any], seconds: float) -> List[float]:
     return [float(mix["ramp_s"]), float(seconds), float(mix["tail_s"])]
 
@@ -90,10 +105,12 @@ def schedule(mix: Mapping[str, Any], vocab: int, seed: int,
         prompts = quantile_lengths(mix["prompt_tokens"], count)
         outputs = quantile_lengths(mix["output_tokens"], count)
         outputs = outputs[np.random.default_rng(0).permutation(count)]
+        block = int(mix["shuffle_block"])
         gaps = -np.log1p(-_quantiles(count)) / rate
-        gaps = rng.permutation(gaps) * (length / gaps.sum())
+        gaps = gaps[block_shuffle(count, block, rng, 1)] \
+            * (length / gaps.sum())
         at = start + np.cumsum(gaps) - gaps
-        for t, i in zip(at, rng.permutation(count)):
+        for t, i in zip(at, block_shuffle(count, block, rng, 2)):
             out.append(Arrival(
                 at_s=float(t),
                 prompt=rng.integers(0, vocab, int(prompts[i]),

@@ -12,7 +12,8 @@ the engine's step hooks as ``serve_loop`` does (``plan``,
 ``begin_prefill``, ``advance``), sends each request at its scheduled
 time (``generator.schedule``), and stamps each output token when the step
 that made it returns.  A request's time to first token runs from its
-scheduled arrival.
+scheduled arrival; the part of it spent waiting to be admitted to a slot
+is noted apart (``wait_p50_s``, ``wait_p90_s``).
 
 After the window, a sample of the requests sent in it goes through the
 plain reference: the mix's longest and every ``check_every``-th from an
@@ -74,6 +75,7 @@ class Loop:
         self.waiting: List[Any] = []
         self.requests: List[Any] = []
         self.stamps: Dict[int, List[float]] = {}  # uid -> token times
+        self.admitted: Dict[int, float] = {}      # uid -> time admitted
         self.lane_steps = {"prefill": 0, "decode": 0}
         self.steps = 0
 
@@ -110,11 +112,13 @@ class Loop:
                 if not engine.add_request(req):
                     break
                 self.waiting.remove(req)
+                self.admitted[req.uid] = time.perf_counter()
             for req in plan.admit_interleaved:
                 if not engine.free_slots():
                     break
                 engine.begin_prefill(req)
                 self.waiting.remove(req)
+                self.admitted[req.uid] = time.perf_counter()
         decoding = list(engine.active.values())
         self.lane_steps["prefill"] += len(engine.prefilling)
         self.lane_steps["decode"] += len(decoding)
@@ -250,14 +254,19 @@ def run(*, cell, config, sizes, mix, seed: int, seconds: float, trace: bool,
     itl = [b - a for r in loop.requests
            for a, b in zip(loop.stamps[r.uid], loop.stamps[r.uid][1:])
            if t0 < b <= t1]
+    waits = [loop.admitted[r.uid] - r.submitted_s for r in in_window
+             if r.uid in loop.admitted]
     done = [r for r in in_window if r.done]
     failed = sent - len(done)
     e2e = {"serve_out_tokens_per_s": tokens / window_s,
+           "serve_ttft_p50_s": percentile(ttft, 50) if ttft else float("inf"),
            "serve_ttft_p90_s": percentile(ttft, 90) if ttft else float("inf"),
            "serve_itl_p90_ms": 1e3 * percentile(itl, 90),
            "setup_s": setup_s}
 
     device = device_info(cell["chips"])
+    step_model_ms = {cls: 1e3 * scheduler.model.tick_cost(sizes["slots"], cls)
+                     for cls in ("warm", "cold")}
     t_check = time.perf_counter()
     checked = sample_requests(done)
     served = served_rows(checked)
@@ -275,6 +284,9 @@ def run(*, cell, config, sizes, mix, seed: int, seconds: float, trace: bool,
                  checked_tokens=numbers["tokens"], logit=numbers["logit"],
                  compiles_in_window=compiles.count,
                  ttft_samples=len(ttft), itl_samples=len(itl),
+                 wait_p50_s=percentile(waits, 50) if waits else None,
+                 wait_p90_s=percentile(waits, 90) if waits else None,
+                 step_model_ms=step_model_ms,
                  drain_s=t_check - t_drain,
                  check_s=time.perf_counter() - t_check,
                  run_s=time.perf_counter() - t_start)
