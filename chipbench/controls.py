@@ -14,7 +14,16 @@ readings are those of that fault.  Each
 seed's readings are printed as one JSON line, with whether the control,
 held to the same limits, comes out correct (it must not); the last line
 holds, for each compared number, the largest program reading and the
-smallest control reading.  The benchmark's own runs never run this.
+smallest control reading.
+
+With ``--fault wrong_pick`` (a blocked cell) each run's regret check is
+read again with the window's pick replaced by each other candidate in
+turn (``kinds/blocked.py``, ``wrong_picks``): each candidate's
+head-to-head regret and its margin over the fastest in the run's sweep.
+The last line holds, for each candidate, its least and largest regret
+and margin over the seeds, and whether every candidate more than
+``SLOWER`` slower than the fastest failed the limit in every run.  The
+benchmark's own runs never run this.
 """
 
 from __future__ import annotations
@@ -46,7 +55,31 @@ def alter_tokens() -> None:
     ServeEngine.advance = altered
 
 
+#: faults planted in the program before the runs
 FAULTS = {"altered_token": alter_tokens}
+#: read after each run of a blocked cell from what the run held
+WRONG_PICK = "wrong_pick"
+#: the margin over the fastest beyond which a wrong pick must fail
+SLOWER = 0.10
+
+
+def wrong_pick_summary(readings) -> dict:
+    """Each candidate's least and largest regret and margin over the
+    seeds, and whether each that was more than ``SLOWER`` slower than the
+    fastest failed the limit in every run."""
+    by_cand = {}
+    for seed in readings:
+        for cand, r in seed.items():
+            by_cand.setdefault(cand, []).append(r)
+    return {
+        "candidates": {c: {"regret": [min(r["regret"] for r in rs),
+                                      max(r["regret"] for r in rs)],
+                           "margin": [min(r["margin"] for r in rs),
+                                      max(r["margin"] for r in rs)]}
+                       for c, rs in sorted(by_cand.items())},
+        "every_slower_pick_fails": all(
+            r["fails"] for rs in by_cand.values() for r in rs
+            if r["margin"] > SLOWER)}
 
 
 def main(argv=None) -> int:
@@ -54,7 +87,7 @@ def main(argv=None) -> int:
     p.add_argument("--workload", required=True)
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--seeds", type=int, nargs="+", required=True)
-    p.add_argument("--fault", choices=sorted(FAULTS))
+    p.add_argument("--fault", choices=sorted([*FAULTS, WRONG_PICK]))
     args = p.parse_args(argv)
     bench = load_json(ROOT / "BENCHMARK.json")
     cell = bench_run.by_name(bench["workloads"], args.workload, "workload")
@@ -68,13 +101,21 @@ def main(argv=None) -> int:
     sizes = load_json(ROOT / config["file"])
     mix = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
     kind = load_module(BENCH / "kinds" / f"{sizes['kind']}.py")
-    if args.fault:
+    if args.fault in FAULTS:
         FAULTS[args.fault]()
-    program, control = [], []
+    program, control, faults = [], [], []
     for seed in args.seeds:
         out = kind.run(cell=cell, config=config, sizes=sizes, mix=mix,
                        seed=seed, seconds=args.seconds, trace=False,
                        t_start=time.perf_counter(), hold=True)
+        if args.fault == WRONG_PICK:
+            faults.append(kind.wrong_picks(out.held))
+            print(json.dumps({"seed": seed, WRONG_PICK: faults[-1],
+                              "checks": out.check.items,
+                              "notes": out.notes}), flush=True)
+            del out
+            gc.collect()
+            continue
         program.append({k: v["value"] for k, v in out.check.items.items()})
         program[-1].update({f"logit.{k}": v for k, v in
                             out.notes.get("logit", {}).items()})
@@ -91,6 +132,11 @@ def main(argv=None) -> int:
                           "notes": out.notes}), flush=True)
         del out
         gc.collect()
+    if faults:
+        print(json.dumps({"workload": args.workload, "seeds": len(faults),
+                          WRONG_PICK: wrong_pick_summary(faults)}),
+              flush=True)
+        return 0
     print(json.dumps({
         "workload": args.workload, "seeds": len(program),
         "program_max": {k: max(p[k] for p in program) for k in program[0]},

@@ -14,15 +14,21 @@ the variant and block size from the models, and the pick runs on
 every problem solved over the window.  After the window two numbers
 decide ``correct``: the factors of the first problem of each size (the
 seed draws their order) against the plain reference (``factor_err``),
-and the selection: the window's pick at ``regret_size`` against every
-candidate measured on the same matrix (``pick_regret``).  A traced run
-also records a few more problems under the profiler.
+and the selection (``pick_regret``): every candidate is timed on the same
+matrix at ``regret_size`` to find the fastest, and the window's pick is
+then timed head to head against that fastest, in ``regret_pairs``
+back-to-back pairs, each timing ``regret_calls`` runs, with the garbage
+collector paused; the regret is the median of the pairs' ratios, less
+one.  A traced run also records a few
+more problems under the profiler, before the check.
 """
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import time
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, Iterator, List, Tuple
 
 import numpy as np
 
@@ -127,25 +133,92 @@ class Solver:
         return self.execute(a, name, b)
 
 
-def regret(solver: Solver, a: np.ndarray, pick: Tuple[str, int],
-           repetitions: int) -> Dict[str, Any]:
-    """Measured seconds of every candidate on ``a`` (median of
-    ``repetitions``, after one run that loads its programs), and the
-    regret of ``pick``: its time over the fastest candidate's, less one."""
+@contextlib.contextmanager
+def collector_paused() -> Iterator[None]:
+    """Collect garbage, then keep the collector paused while the block
+    times its calls; it is switched on again however the block ends."""
+    gc.collect()
+    was_on = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was_on:
+            gc.enable()
+
+
+def timed(solver: Solver, a: np.ndarray, cand: Tuple[str, int],
+          calls: int) -> float:
+    """Seconds of one run of ``cand`` on ``a``: the mean of ``calls`` runs
+    back to back, so that the host clock's error is a small part of the
+    time it reads (one run at n=2048 takes about 6 ms on a TPU v5e)."""
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        solver.execute(a, *cand)
+    return (time.perf_counter() - t0) / calls
+
+
+def sweep(solver: Solver, a: np.ndarray, repetitions: int,
+          calls: int) -> Dict[Tuple[str, int], float]:
+    """Median seconds of every candidate on ``a`` over ``repetitions``
+    round-robin rounds, after one run of each that loads its programs."""
     cands = [(name, b) for name in solver.tracers for b in solver.block_sizes]
     for c in cands:
         solver.execute(a, *c)
     times: Dict[Tuple[str, int], List[float]] = {c: [] for c in cands}
     for _ in range(repetitions):
         for c in cands:
-            t0 = time.perf_counter()
-            solver.execute(a, *c)
-            times[c].append(time.perf_counter() - t0)
-    med = {c: float(np.median(v)) for c, v in times.items()}
-    best = min(med, key=med.get)
-    return {"pick": list(pick), "fastest": list(best),
-            "regret": med[pick] / med[best] - 1.0,
-            "seconds": {f"{n}/{b}": t for (n, b), t in med.items()}}
+            times[c].append(timed(solver, a, c, calls))
+    return {c: float(np.median(v)) for c, v in times.items()}
+
+
+def head_to_head(solver: Solver, a: np.ndarray, pick: Tuple[str, int],
+                 fastest: Tuple[str, int], pairs: int,
+                 calls: int) -> List[float]:
+    """``pairs`` ratios of the pick's seconds over the fastest's on ``a``,
+    each of two timings back to back, which of them goes first
+    alternating."""
+    ratios = []
+    for i in range(pairs):
+        if i % 2:
+            t_fast = timed(solver, a, fastest, calls)
+            t_pick = timed(solver, a, pick, calls)
+        else:
+            t_pick = timed(solver, a, pick, calls)
+            t_fast = timed(solver, a, fastest, calls)
+        ratios.append(t_pick / t_fast)
+    return ratios
+
+
+def pair_regret(solver: Solver, a: np.ndarray, pick: Tuple[str, int],
+                fastest: Tuple[str, int], pairs: int,
+                calls: int) -> Dict[str, Any]:
+    """The regret of ``pick`` against ``fastest``: the median of their
+    head-to-head ratios, less one; 0 where the pick is the fastest, or
+    where it beats the sweep's fastest head to head (it is then the
+    faster of the two)."""
+    if tuple(pick) == tuple(fastest):
+        return {"regret": 0.0, "ratios": []}
+    with collector_paused():
+        ratios = head_to_head(solver, a, pick, fastest, pairs, calls)
+    return {"regret": max(0.0, float(np.median(ratios)) - 1.0),
+            "ratios": ratios}
+
+
+def regret(solver: Solver, a: np.ndarray, pick: Tuple[str, int],
+           sizes: Dict[str, Any]) -> Dict[str, Any]:
+    """The fastest of every candidate on ``a`` (``sweep``), and the regret
+    of ``pick`` against it, timed head to head (``pair_regret``), with the
+    configuration's ``regret_repetitions``, ``regret_pairs`` and
+    ``regret_calls``."""
+    calls = sizes["regret_calls"]
+    with collector_paused():
+        seconds = sweep(solver, a, sizes["regret_repetitions"], calls)
+    fastest = min(seconds, key=seconds.get)
+    return {"pick": list(pick), "fastest": list(fastest),
+            **pair_regret(solver, a, pick, fastest, sizes["regret_pairs"],
+                          calls),
+            "seconds": {f"{n}/{b}": t for (n, b), t in seconds.items()}}
 
 
 def run(*, cell, config, sizes, mix, seed: int, seconds: float, trace: bool,
@@ -210,8 +283,7 @@ def run(*, cell, config, sizes, mix, seed: int, seconds: float, trace: bool,
     check = Check()
     n = sizes["regret_size"]
     if n in picks:
-        counters["regret"] = regret(solver, inputs[n], picks[n],
-                                    sizes["regret_repetitions"])
+        counters["regret"] = regret(solver, inputs[n], picks[n], sizes)
         notes["regret"] = dict(counters["regret"],
                                s=time.perf_counter() - t_check)
     check.add("pick_regret", counters["regret"]["regret"]
@@ -233,7 +305,10 @@ def run(*, cell, config, sizes, mix, seed: int, seconds: float, trace: bool,
         notes=notes)
     if hold:
         outcome.held = {"ref": ref, "inputs": [inputs[n] for n, _ in
-                                               answers.values()]}
+                                               answers.values()],
+                        "solver": solver, "sizes": sizes,
+                        "regret_input": inputs.get(sizes["regret_size"]),
+                        "regret": counters.get("regret")}
     return outcome
 
 
@@ -243,3 +318,24 @@ def control(held) -> Dict[str, float]:
     ref = held["ref"]
     return {"factor_err": max(ref.error(ref.control(a), ref.reference(a))
                               for a in held["inputs"])}
+
+
+def wrong_picks(held) -> Dict[str, Dict[str, Any]]:
+    """The regret check with the window's pick at ``regret_size`` replaced
+    by each other candidate in turn: each one's head-to-head regret
+    against the run's fastest, its margin over the fastest in the run's
+    sweep, and whether it fails the limit."""
+    sizes, found = held["sizes"], held["regret"]
+    fastest = tuple(found["fastest"])
+    best = found["seconds"]["/".join(map(str, fastest))]
+    out = {}
+    for key, seconds in found["seconds"].items():
+        name, b = key.split("/")
+        if [name, int(b)] == found["pick"]:
+            continue
+        got = pair_regret(held["solver"], held["regret_input"],
+                          (name, int(b)), fastest, sizes["regret_pairs"],
+                          sizes["regret_calls"])
+        out[key] = {"regret": got["regret"], "margin": seconds / best - 1.0,
+                    "fails": got["regret"] > sizes["regret_limit"]}
+    return out

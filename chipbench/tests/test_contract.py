@@ -3,8 +3,9 @@ configuration, mix, kind and metric readers by name."""
 
 import json
 import re
+from types import SimpleNamespace
 
-from common import BENCH, ROOT
+from common import BENCH, ROOT, Check, Outcome, Spans
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -88,3 +89,90 @@ def test_off_a_tpu_the_command_prints_no_result(tmp_path):
     assert proc.returncode == 3, proc.stderr
     assert proc.stdout == ""
     assert "TPU" in proc.stderr
+
+
+def test_a_metric_lists_cells_of_the_file():
+    # a metric reported by no cell cannot stand in BENCHMARK.json: its
+    # workloads, where given, name one cell or more, each in the file
+    cells = {w["name"] for w in SPEC["workloads"]}
+    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
+        if "workloads" in m:
+            assert isinstance(m["workloads"], list) and m["workloads"]
+            assert set(m["workloads"]) <= cells, m["name"]
+
+
+#: metrics of the served cell, kept beside the benchmark until it has one
+PENDING = json.loads((BENCH / "serve_metrics.json").read_text())
+
+
+def pending(kind):
+    return {m["name"] for m in PENDING[kind]}
+
+
+def test_the_serving_metrics_wait_outside_the_benchmark():
+    import run
+    assert pending("end_to_end") == {"serve_out_tokens_per_s",
+                                     "serve_ttft_p50_s", "serve_itl_p90_ms"}
+    assert "serve.slot_wait_p90_ms" in pending("per_layer")
+    layers = {m["layer"] for m in SPEC["per_layer"]}
+    for m in PENDING["end_to_end"] + PENDING["per_layer"]:
+        assert "workloads" not in m
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+    for m in PENDING["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    for m in PENDING["per_layer"]:
+        assert m["moves"] in pending("end_to_end")
+        assert (BENCH / "metrics" / f"{m['name']}.py").exists()
+        # a layer the benchmark already names keeps its name
+        module = m["layer"].split(" ")[0]
+        assert all(l == m["layer"] for l in layers
+                   if l.split(" ")[0] == module)
+    for kind in ("end_to_end", "per_layer"):
+        assert not pending(kind) & {m["name"] for m in SPEC[kind]}
+        for w in SPEC["workloads"]:
+            reported = {m["name"] for m in run.cell_metrics(SPEC, w["name"],
+                                                            kind)}
+            assert not reported & pending(kind)
+
+
+def test_run_cell_never_looks_up_a_metric_of_no_cell(monkeypatch):
+    # the kind hands back only the cell's own end-to-end metrics, and
+    # every module the run loads is recorded
+    import run
+    loaded = []
+    for cell in SPEC["workloads"]:
+        e2e = {m["name"]: 1.0
+               for m in run.cell_metrics(SPEC, cell["name"], "end_to_end")}
+        check = Check()
+        check.add("n", 0.0, 1.0)
+        outcome = Outcome(
+            e2e=e2e, counters={}, check=check, attempted=1, failed=0,
+            device={}, spans=Spans(), sizes={},
+            reduced=SimpleNamespace(busy_s=1.0, window_s=2.0,
+                                    breakdown=lambda: {}))
+
+        def load_module(path, outcome=outcome):
+            loaded.append(path.stem)
+            return SimpleNamespace(run=lambda **_: outcome,
+                                   read=lambda _run: 1.0)
+
+        monkeypatch.setattr(run, "load_module", load_module)
+        for trace in (0, 1):
+            args = SimpleNamespace(seed=1, seconds=1.0, trace=trace)
+            line = run.run_cell(SPEC, cell, args, t_start=0.0)
+            assert not set(line["metrics"]) & pending("end_to_end")
+            assert not set(line["metrics"]) & pending("per_layer")
+    assert loaded and not set(loaded) & pending("per_layer")
+
+
+def test_no_file_names_the_rescaled_step_time():
+    # the served step's share of peak, taken out: it was the step time
+    # rescaled, and counted empty slots
+    gone = "serve." + "step_mfu"
+    assert not (BENCH / "metrics" / f"{gone}.py").exists()
+    files = [ROOT / "BENCHMARK.json"] + [
+        p for p in BENCH.rglob("*") if p.suffix in (".py", ".json", ".md")
+        and ".state" not in p.parts]
+    assert not [p for p in files if gone in p.read_text()]
