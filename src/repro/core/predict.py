@@ -10,6 +10,7 @@ Taylor corrections for the mean/std of the reciprocal.
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -200,13 +201,18 @@ class TraceCache:
     it is the one remaining Python loop on the prediction hot path.  The
     cache is keyed on ``(tracer identity, n, b)`` (the tracer object itself,
     which also keeps it alive, so ids are never recycled into stale hits)
-    and additionally memoizes whole compiled sweep/grid batches, so repeated
-    sweeps over the same candidate set reuse one :class:`CompiledCalls`
-    instead of re-tracing and re-grouping every point.
+    and additionally memoizes whole compiled sweep/grid/selection batches,
+    so repeated sweeps over the same candidate set reuse one
+    :class:`CompiledCalls` instead of re-tracing and re-grouping every
+    point.  Neither depends on the models, so one cache serves any number
+    of model sets.
 
-    Entries are never evicted: hold ON to the tracer objects you sweep with
-    (rebuilding a tracer closure per request defeats the cache and grows it
-    unboundedly in a long-lived shared engine); call :meth:`clear` to reset.
+    A cache a caller owns (``TraceCache()``, ``cache=``) never evicts its
+    entries: hold ON to the tracer objects you sweep with (rebuilding a
+    tracer closure per request defeats the cache and grows it unboundedly
+    in a long-lived shared engine); call :meth:`clear` to reset.  The one
+    the selection entry points share when given no engine is bounded
+    (:data:`IMPLICIT_CACHE_ENTRIES`).
     """
 
     def __init__(self):
@@ -215,51 +221,102 @@ class TraceCache:
         self.hits = 0
         self.misses = 0
 
+    def _lookup(self, table: Dict, key: Tuple):
+        return table.get(key)
+
+    def _store(self, table: Dict, key: Tuple, value) -> None:
+        table[key] = value
+
     def calls(self, tracer: Tracer, n: int, b: int) -> Tuple[KernelCall, ...]:
         """The (cached) call sequence of one traced configuration; each
         miss traces it in one ``predict.trace`` profiler span (stats
         ``n``, ``b``)."""
         key = (tracer, n, b)
-        out = self._calls.get(key)
+        out = self._lookup(self._calls, key)
         if out is None:
             self.misses += 1
             with _span("predict.trace", n=n, b=b):
                 out = tuple(tracer(n, b))
-            self._calls[key] = out
+            self._store(self._calls, key, out)
         else:
             self.hits += 1
         return out
 
+    def _compiled_batch(self, key: Tuple,
+                        points: Sequence[Tuple[Tracer, int, int]], *,
+                        hits: int = 1) -> CompiledCalls:
+        out = self._lookup(self._compiled, key)
+        if out is None:
+            out = compile_calls([self.calls(*p) for p in points])
+            self._store(self._compiled, key, out)
+        else:
+            self.hits += hits   # whole-batch reuse: calls() is never consulted
+        return out
+
+    def compiled_selection(self, tracers: Sequence[Tracer], n: int,
+                           candidates: Sequence[int]) -> CompiledCalls:
+        """One reusable compiled batch for a tracer set x block-size grid
+        at one ``n``, tracer-major: the batch a joint variant and
+        block-size selection evaluates.  A hit traces and groups nothing,
+        so it emits no ``predict.trace`` or ``predict.compile`` span; it
+        counts one hit per configuration, as the per-configuration
+        lookups it replaces did."""
+        tracers, candidates = tuple(tracers), tuple(candidates)
+        points = [(t, n, b) for t in tracers for b in candidates]
+        return self._compiled_batch(("selection", tracers, n, candidates),
+                                    points, hits=len(points))
+
     def compiled_sweep(self, tracer: Tracer, n: int,
                        candidates: Sequence[int]) -> CompiledCalls:
         """One reusable compiled batch for a whole block-size sweep."""
-        key = ("sweep", tracer, n, tuple(candidates))
-        out = self._compiled.get(key)
-        if out is None:
-            out = compile_calls([self.calls(tracer, n, b)
-                                 for b in candidates])
-            self._compiled[key] = out
-        else:
-            self.hits += 1   # whole-batch reuse: calls() is never consulted
-        return out
+        return self._compiled_batch(
+            ("sweep", tracer, n, tuple(candidates)),
+            [(tracer, n, b) for b in candidates])
 
     def compiled_grid(self, tracer: Tracer, ns: Sequence[int],
                       bs: Sequence[int]) -> CompiledCalls:
         """One reusable compiled batch for a full (n, b) grid."""
-        key = ("grid", tracer, tuple(ns), tuple(bs))
-        out = self._compiled.get(key)
-        if out is None:
-            out = compile_calls([self.calls(tracer, n, b)
-                                 for n in ns for b in bs])
-            self._compiled[key] = out
-        else:
-            self.hits += 1   # whole-batch reuse: calls() is never consulted
-        return out
+        return self._compiled_batch(
+            ("grid", tracer, tuple(ns), tuple(bs)),
+            [(tracer, n, b) for n in ns for b in bs])
 
     def clear(self) -> None:
         self._calls.clear()
         self._compiled.clear()
         self.hits = self.misses = 0
+
+
+#: entries each table (call sequences, compiled batches) of the implicit
+#: engines' shared :class:`TraceCache` keeps before it evicts the least
+#: recently used: a few hundred (n, b) points, and as many selections
+IMPLICIT_CACHE_ENTRIES = 256
+
+
+class _ImplicitTraceCache(TraceCache):
+    """The process-wide cache of the engines :func:`resolve_engine` builds
+    when a caller passes none.  No caller sees it, so it is bounded: past
+    :data:`IMPLICIT_CACHE_ENTRIES` in a table the least recently used
+    entry goes.  A lock keeps the recency order whole across threads."""
+
+    def __init__(self):
+        super().__init__()
+        self._lock = threading.Lock()
+
+    def _lookup(self, table: Dict, key: Tuple):
+        with self._lock:
+            out = table.pop(key, None)
+            if out is not None:
+                table[key] = out      # dicts keep insertion order: now last
+            return out
+
+    def _store(self, table: Dict, key: Tuple, value) -> None:
+        with self._lock:
+            table[key] = value
+            while len(table) > IMPLICIT_CACHE_ENTRIES:
+                del table[next(iter(table))]
+
+
+_IMPLICIT_CACHE = _ImplicitTraceCache()
 
 
 #: evaluation backends a PredictionEngine can run its stacked polynomial
@@ -397,11 +454,12 @@ class PredictionEngine:
     fused path's equivalence oracle.
 
     Every engine owns a :class:`TraceCache` (pass ``cache=`` to share one
-    across engines): ``sweep``/``grid`` compile their whole candidate set
-    once into a reusable :class:`CompiledCalls` artifact — also available
-    directly via :meth:`compile_sweep`/:meth:`compile_grid` — so repeated
-    sweeps skip both the Python tracing loop and the re-grouping, and
-    ``predict_compiled`` consumes the artifact directly.
+    across engines; the engines :func:`resolve_engine` builds share one
+    bounded process-wide cache): ``sweep``/``grid`` compile their whole
+    candidate set once into a reusable :class:`CompiledCalls` artifact —
+    also available directly via :meth:`compile_sweep`/:meth:`compile_grid`
+    — so repeated sweeps skip both the Python tracing loop and the
+    re-grouping, and ``predict_compiled`` consumes the artifact directly.
     """
 
     def __init__(self, models: ModelSet, *, backend: str = "numpy",
@@ -572,7 +630,9 @@ def resolve_engine(models: ModelSet, backend: Optional[str],
     An explicit ``backend`` (or the ``models`` argument itself) must not be
     silently overridden by a supplied engine — conflicting requests raise
     instead of handing back results from the wrong evaluation path or the
-    wrong model set.
+    wrong model set.  Without an engine the new one shares the bounded
+    process-wide trace cache, so a caller that never keeps an engine still
+    traces and compiles each candidate batch once.
     """
     if engine is not None:
         if backend is not None and backend != engine.backend:
@@ -585,7 +645,8 @@ def resolve_engine(models: ModelSet, backend: Optional[str],
                 "the models argument; predictions would silently come from "
                 "the engine's set")
         return engine
-    return PredictionEngine(models, backend=backend or "numpy")
+    return PredictionEngine(models, backend=backend or "numpy",
+                            cache=_IMPLICIT_CACHE)
 
 
 def predict_performance(runtime: Stats, cost_flops: float) -> Dict[str, float]:

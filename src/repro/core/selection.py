@@ -11,9 +11,11 @@ Both entry points run on the vectorized :class:`PredictionEngine` by default
 (the batch of candidate configurations is predicted with a handful of array
 ops); pass ``batched=False`` to fall back to the scalar per-call reference
 path, which is kept as the equivalence oracle.  ``backend="jax"`` evaluates
-the stacked polynomials in jitted XLA programs, and passing a shared
-``engine=`` lets repeated selections reuse its trace cache (traced call
-sequences and compiled sweep batches) instead of re-tracing.
+the stacked polynomials in jitted XLA programs.  Repeated selections reuse
+a trace cache (traced call sequences and each compiled candidate batch)
+instead of re-tracing: the ``engine=``'s own, or without one a bounded
+cache shared by every engine built for a call that passed none.  Either
+way each selection evaluates every candidate's models anew.
 
 :func:`select_contraction_algorithm` extends the same selection interface
 to tensor contractions (paper Ch. 6) via :mod:`repro.tc` — micro-benchmark
@@ -58,8 +60,10 @@ def rank_algorithms(tracers: Mapping[str, Tracer], models: ModelSet,
     names = list(tracers)
     if batched:
         eng = resolve_engine(models, backend, engine)
-        runtimes = eng.predict_stats([eng.cache.calls(tracers[name], n, b)
-                                      for name in names])
+        compiled = eng.cache.compiled_selection(
+            [tracers[name] for name in names], n, [b])
+        runtimes = [Stats(*map(float, row))
+                    for row in eng.predict_compiled(compiled)]
     else:
         runtimes = [predict_runtime(tracers[name](n, b), models)
                     for name in names]
@@ -115,8 +119,8 @@ def optimize_algorithm_and_block_size(
         eng = resolve_engine(models, backend, engine)
         names = list(tracers)
         col = STATS.index(stat)
-        vals = eng.predict_batch([eng.cache.calls(tracers[name], n, b)
-                                  for name in names for b in candidates])
+        vals = eng.predict_compiled(eng.cache.compiled_selection(
+            [tracers[name] for name in names], n, candidates))
         grid = vals[:, col].reshape(len(names), len(candidates))
         flat = int(grid.argmin())
         vi, bi = divmod(flat, len(candidates))

@@ -250,3 +250,89 @@ def test_grid_prediction_shape_and_values():
             ref = predict_runtime(tracer(n, b), ms)
             for k, s in enumerate(STATS):
                 assert _rel_close(grid[i, j, k], getattr(ref, s)), (n, b, s)
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_default_engine_answers_each_model_set_interleaved(backend):
+    """The shared trace cache of engine-less calls holds no model: two
+    model sets selecting in turn over the same tracers and sizes each get
+    their own fresh-engine answer."""
+    tracers = {"a": _tracer_for("k"), "b": _tracer_for("k2"),
+               "c": _tracer_for("k", calls_per_iter=3)}
+    sets = [ModelSet({"k": _random_model(np.random.default_rng(seed), "k"),
+                      "k2": _random_model(np.random.default_rng(seed + 1),
+                                          "k2")})
+            for seed in (41, 43)]
+    candidates = [16, 32, 64]
+    want = [optimize_algorithm_and_block_size(
+        tracers, ms, 512, candidates,
+        engine=PredictionEngine(ms, backend=backend)) for ms in sets]
+    assert want[0] != want[1]
+    for _ in range(2):
+        for ms, ref in zip(sets, want):
+            assert optimize_algorithm_and_block_size(
+                tracers, ms, 512, candidates, backend=backend) == ref
+
+
+def test_default_engine_sees_a_replaced_kernel_model():
+    """Replacing a kernel model with ``ModelSet.add`` changes the next
+    engine-less answer, though its batch comes from the shared cache."""
+    tracers = {"a": _tracer_for("k"), "b": _tracer_for("k2")}
+    ms = ModelSet({"k": _random_model(np.random.default_rng(51), "k"),
+                   "k2": _random_model(np.random.default_rng(52), "k2")})
+    before = rank_algorithms(tracers, ms, 384, 32)
+    ms.add(_random_model(np.random.default_rng(53), "k"))
+    after = rank_algorithms(tracers, ms, 384, 32)
+    ref = rank_algorithms(tracers, ms, 384, 32, batched=False)
+    assert [r.runtime for r in after] != [r.runtime for r in before]
+    assert [r.name for r in after] == [r.name for r in ref]
+    for g, r in zip(after, ref):
+        assert _rel_close(g.runtime.med, r.runtime.med)
+
+
+def test_default_engine_cache_evicts_least_recently_used():
+    """Engine-less selections past the shared cache's bound evict the
+    least recently used entries, and no table ever holds more."""
+    from repro.core import predict
+
+    cache = predict._IMPLICIT_CACHE
+    bound = predict.IMPLICIT_CACHE_ENTRIES
+    ms = ModelSet({"k": _random_model(np.random.default_rng(61), "k")})
+    tracer = _tracer_for("k")
+    ns = list(range(8, 8 + 2 * bound))
+    for i, n in enumerate(ns):
+        optimize_block_size(tracer, ms, n, [8])
+        optimize_block_size(tracer, ms, ns[0], [8])   # keep it recent
+        assert len(cache._calls) <= bound
+        assert len(cache._compiled) <= bound
+    held = {key[2] for key in cache._compiled if key[1] is tracer}
+    assert ns[0] in held and ns[-1] in held
+    assert ns[1] not in held and ns[-bound] not in held
+    # a whole-batch hit reads no call sequence, so ns[0]'s has aged out
+    assert {key[1] for key in cache._calls if key[0] is tracer} == \
+        set(ns[-bound:])
+
+
+def test_caller_engine_keeps_its_own_cache_and_counts():
+    """An engine a caller builds keeps its own cache, and selections on it
+    count one miss per traced configuration and one hit per configuration
+    served from the cache, whole batch or not."""
+    from repro.core import predict
+
+    tracers = {"a": _tracer_for("k"), "b": _tracer_for("k2"),
+               "c": _tracer_for("k", calls_per_iter=2)}
+    ms = ModelSet({"k": _random_model(np.random.default_rng(71), "k"),
+                   "k2": _random_model(np.random.default_rng(72), "k2")})
+    eng = PredictionEngine(ms)
+    assert eng.cache is not predict._IMPLICIT_CACHE
+    candidates = [16, 32, 64]
+    configs = len(tracers) * len(candidates)
+    first = optimize_algorithm_and_block_size(tracers, ms, 512, candidates,
+                                              engine=eng)
+    assert (eng.cache.misses, eng.cache.hits) == (configs, 0)
+    assert optimize_algorithm_and_block_size(
+        tracers, ms, 512, candidates, engine=eng) == first
+    assert (eng.cache.misses, eng.cache.hits) == (configs, configs)
+    rank_algorithms(tracers, ms, 512, 32, engine=eng)  # traced at b=32
+    assert (eng.cache.misses, eng.cache.hits) == \
+        (configs, configs + len(tracers))

@@ -238,6 +238,34 @@ def test_selection_traces_compiles_and_evaluates_in_spans(tmp_path,
     assert out["pick"][0] in CHOLESKY_TRACERS
 
 
+@pytest.mark.parametrize("n", [256, 320, 384])
+def test_default_engine_selection_evaluates_a_cached_batch(tmp_path, n):
+    """Without ``engine=`` a second selection at the same n neither traces
+    nor compiles: one ``predict.evaluate``, and the answer a fresh engine
+    and the scalar oracle give."""
+    models = catalog_synthetic_model_set()
+    candidates = [32, 64, 128]
+    optimize_algorithm_and_block_size(CHOLESKY_TRACERS, models, n,
+                                      candidates)
+    out = {}
+
+    def work():
+        out["pick"] = optimize_algorithm_and_block_size(
+            CHOLESKY_TRACERS, models, n, candidates)
+
+    spans = recorded_spans(tmp_path, work)
+    assert [(name, st) for _, name, st in spans] == \
+        [("predict.evaluate", {"backend": "numpy"})]
+    fresh = optimize_algorithm_and_block_size(
+        CHOLESKY_TRACERS, models, n, candidates,
+        engine=PredictionEngine(models))
+    assert out["pick"] == fresh
+    oracle = optimize_algorithm_and_block_size(
+        CHOLESKY_TRACERS, models, n, candidates, batched=False)
+    assert out["pick"][:2] == oracle[:2]
+    assert out["pick"][2] == pytest.approx(oracle[2], rel=1e-10)
+
+
 LEVEL3 = {"gemm": K._gemm_fn, "syrk": K._syrk_fn, "syr2k": K._syr2k_fn,
           "symm": K._symm_fn, "trsm": K._trsm_fn, "trmm": K._trmm_fn}
 
