@@ -16,7 +16,7 @@ import numpy as np
 from repro.dla import ExecEngine, blocked
 from repro.dla.tracers import (CHOLESKY_TRACERS, SYLVESTER_TRACERS,
                                TRTRI_TRACERS)
-from repro.core import rank_algorithms
+from repro.core import predict_runtime, rank_algorithms
 
 from .common import build_model_set, lower_nonsing, median_time, spd
 
@@ -65,13 +65,15 @@ def run(report: List[str]) -> None:
         ranked = rank_algorithms(tracers, ms, N, B)
         t_pred = time.perf_counter() - t0
         t0 = time.perf_counter()
-        ranked_scalar = rank_algorithms(tracers, ms, N, B, batched=False)
+        scalar = {name: predict_runtime(tracer(N, B), ms).med
+                  for name, tracer in tracers.items()}
         t_pred_scalar = time.perf_counter() - t0
+        scalar_winner = min(scalar, key=scalar.get)
         # numerically tied variants may swap winners between the two paths'
         # summation orders — only a >1e-9 relative disagreement is a bug
-        assert (ranked_scalar[0].name == ranked[0].name
-                or abs(ranked_scalar[0].runtime.med - ranked[0].runtime.med)
-                <= 1e-9 * max(ranked_scalar[0].runtime.med, 1e-300))
+        assert (scalar_winner == ranked[0].name
+                or abs(scalar[scalar_winner] - ranked[0].runtime.med)
+                <= 1e-9 * max(scalar[scalar_winner], 1e-300))
         t0 = time.perf_counter()
         measured = _measure_all(catalog)
         t_meas = time.perf_counter() - t0

@@ -4,14 +4,9 @@ The paper's promise is *instantaneous* model-based selection (§4.5/§4.6).
 This suite times a block-size sweep and a multi-variant ranking on the scalar
 per-call reference path vs the vectorized :class:`PredictionEngine`, checks
 that both select the same configuration with statistics agreeing to ~1e-10,
-and reports the sweep speedup.  It also pits the engine's backends against
-each other on a fixed 64-candidate sweep: the plain NumPy batched path
-(re-traced every call, as in PR 1), the numpy + trace-cached path, the
-pre-fusion per-group jax path (one jitted program per (kernel, case)
-group plus host-side bincounts) and the fused path (``backend="jax"``
-with the whole compiled batch as ONE jitted dispatch) — the
-``sweep64_*`` metrics CI tracks across commits, including the
-fused-vs-grouped speedup and the jax-vs-numpy backend ratio.  The models are analytic
+and reports the sweep speedup.  It also times a fixed 64-candidate sweep
+re-traced every call and trace-cached — the ``sweep64_*`` metrics CI
+tracks across commits.  The models are analytic
 (measurement-free, ``common.synthetic_model_set``), so the suite runs
 identically on any machine — it is also the CI smoke lane's
 perf-trajectory probe.
@@ -22,10 +17,8 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional
 
-import numpy as np
-
 from repro.core import (PredictionEngine, optimize_block_size,
-                        rank_algorithms)
+                        predict_runtime, rank_algorithms)
 from repro.core.sampler import STATS
 from repro.dla.tracers import CHOLESKY_TRACERS, TRTRI_TRACERS, potrf_tracer
 
@@ -43,14 +36,17 @@ def run(report: List[str],
     ms = synthetic_model_set()
     tracer = potrf_tracer(3)
 
+    def scalar_sweep():
+        profile = {b: predict_runtime(tracer(n, b), ms).med
+                   for b in candidates}
+        return min(profile, key=profile.get), profile
+
     # ---- block-size sweep: scalar loop vs batched engine ----
-    b_scalar, prof_scalar = optimize_block_size(tracer, ms, n, candidates,
-                                                batched=False)
+    b_scalar, prof_scalar = scalar_sweep()
     b_batched, prof_batched = optimize_block_size(tracer, ms, n, candidates)
     max_rel = max(abs(prof_batched[b] - prof_scalar[b]) /
                   max(prof_scalar[b], 1e-300) for b in candidates)
-    t_scalar = _best_of(lambda: optimize_block_size(
-        tracer, ms, n, candidates, batched=False), reps)
+    t_scalar = _best_of(scalar_sweep, reps)
     t_batched = _best_of(lambda: optimize_block_size(
         tracer, ms, n, candidates), reps)
     speedup = t_scalar / t_batched
@@ -63,19 +59,23 @@ def run(report: List[str],
     # ---- multi-variant ranking (11 algorithms in one compiled batch) ----
     tracers = {**CHOLESKY_TRACERS, **TRTRI_TRACERS}
     b_rank = candidates[len(candidates) // 2]
-    ranked_scalar = rank_algorithms(tracers, ms, n, b_rank, batched=False)
-    t_rank_scalar = _best_of(lambda: rank_algorithms(
-        tracers, ms, n, b_rank, batched=False), reps)
+
+    def scalar_rank():
+        runtimes = {name: predict_runtime(t(n, b_rank), ms)
+                    for name, t in tracers.items()}
+        return sorted(runtimes.items(), key=lambda kv: kv[1].med)
+
+    ranked_scalar = scalar_rank()
+    t_rank_scalar = _best_of(scalar_rank, reps)
     ranked_batched = rank_algorithms(tracers, ms, n, b_rank)
     t_rank_batched = _best_of(lambda: rank_algorithms(
         tracers, ms, n, b_rank), reps)
     # variants with numerically-tied predictions may swap under the two
     # paths' different summation orders; only a >1e-9 inversion is a mismatch
     order_agree = all(
-        s.name == b.name
-        or abs(s.runtime.med - b.runtime.med)
-        <= 1e-9 * max(abs(s.runtime.med), 1e-300)
-        for s, b in zip(ranked_scalar, ranked_batched))
+        name == b.name
+        or abs(rt.med - b.runtime.med) <= 1e-9 * max(abs(rt.med), 1e-300)
+        for (name, rt), b in zip(ranked_scalar, ranked_batched))
     report.append(
         f"ranking {len(tracers)} variants n={n} b={b_rank}: "
         f"scalar={t_rank_scalar * 1e3:8.1f}ms "
@@ -83,43 +83,21 @@ def run(report: List[str],
         f"speedup={t_rank_scalar / t_rank_batched:6.1f}x "
         f"order {'==' if order_agree else '!='} winner={ranked_batched[0].name}")
 
-    # ---- backends on the fixed 64-candidate sweep (the CI metric) ----
-    # the PR-1 baseline: numpy batched, re-tracing the candidates per call
+    # ---- the fixed 64-candidate sweep (the CI metric) ----
+    # re-tracing the candidates per call, as a fresh engine does
     cand64 = [8 * (i + 1) for i in range(64)]
     t_np64 = _best_of(lambda: PredictionEngine(ms).sweep(
         tracer, n, cand64), max(reps, 15))
-    # fused + trace-cached: candidate set compiled once, the WHOLE batch
-    # (piece lookup, matmuls and the config scatter-add) one jitted
-    # XLA dispatch per sweep
-    eng_jax = PredictionEngine(ms, backend="jax")
-    sweep_jax = eng_jax.sweep(tracer, n, cand64)        # jit + trace warmup
-    t_jax64 = _best_of(lambda: eng_jax.sweep(tracer, n, cand64),
-                       max(reps, 15))
-    # the pre-fusion reference: one jitted program per (kernel, case)
-    # group plus host-side bincounts — what the fused path must beat >=2x
-    compiled64 = eng_jax.compile_sweep(tracer, n, cand64)
-    sweep_jax_grouped = eng_jax.predict_compiled_grouped(compiled64)
-    t_jax64_grouped = _best_of(
-        lambda: eng_jax.predict_compiled_grouped(compiled64), max(reps, 15))
-    # numpy + trace-cached isolates the cache's share of the win
+    # trace-cached: the candidate set is compiled once, so a sweep is one
+    # evaluation of the cached batch
     eng_np = PredictionEngine(ms)
-    sweep_np = eng_np.sweep(tracer, n, cand64)
+    eng_np.sweep(tracer, n, cand64)
     t_npc64 = _best_of(lambda: eng_np.sweep(tracer, n, cand64),
                        max(reps, 15))
-    max_rel_backend = float(np.max(
-        np.abs(sweep_jax - sweep_np) / np.maximum(np.abs(sweep_np), 1e-300)))
-    max_rel_fused = float(np.max(
-        np.abs(sweep_jax - sweep_jax_grouped) /
-        np.maximum(np.abs(sweep_jax_grouped), 1e-300)))
     report.append(
         f"64-candidate sweep n={n}: numpy={t_np64 * 1e3:6.2f}ms "
         f"numpy+cache={t_npc64 * 1e3:6.2f}ms "
-        f"jax grouped={t_jax64_grouped * 1e3:6.2f}ms "
-        f"jax fused={t_jax64 * 1e3:6.2f}ms "
-        f"fused_speedup={t_jax64_grouped / t_jax64:4.1f}x "
-        f"jax{'<' if t_jax64 < t_npc64 else '>='}numpy "
-        f"max_rel_backend_diff={max_rel_backend:.1e} "
-        f"max_rel_fused_diff={max_rel_fused:.1e}")
+        f"cache_speedup={t_np64 / t_npc64:4.1f}x")
 
     # ---- full (n, b) grid in one shot ----
     engine = PredictionEngine(ms)
@@ -148,13 +126,6 @@ def run(report: List[str],
             "rank_order_agree": bool(order_agree),
             "sweep64_numpy_s": t_np64,
             "sweep64_numpy_cached_s": t_npc64,
-            "sweep64_jax_cached_s": t_jax64,
-            "sweep64_jax_grouped_s": t_jax64_grouped,
-            "sweep64_fused_speedup": t_jax64_grouped / t_jax64,
-            "sweep64_jax_beats_numpy": bool(t_jax64 < t_npc64),
-            "sweep64_speedup": t_np64 / t_jax64,
-            "max_rel_backend_diff": max_rel_backend,
-            "max_rel_fused_diff": max_rel_fused,
             "grid_configs": len(ns) * n_cand, "grid_s": t_grid,
         })
 

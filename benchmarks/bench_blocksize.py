@@ -12,7 +12,8 @@ from typing import Dict, List
 
 import numpy as np
 
-from repro.core import optimize_block_size, performance_yield
+from repro.core import (optimize_block_size, performance_yield,
+                        predict_runtime)
 from repro.dla import ExecEngine, blocked
 from repro.dla.tracers import potrf_tracer, trtri_tracer
 
@@ -49,9 +50,9 @@ def run(report: List[str]) -> None:
             b_pred, profile = optimize_block_size(tracer, ms, n, CANDIDATES)
             t_batched = time.perf_counter() - t0
             t0 = time.perf_counter()
-            b_scalar, prof_scalar = optimize_block_size(tracer, ms, n,
-                                                        CANDIDATES,
-                                                        batched=False)
+            prof_scalar = {b: predict_runtime(tracer(n, b), ms).med
+                           for b in CANDIDATES}
+            b_scalar = min(prof_scalar, key=prof_scalar.get)
             t_scalar = time.perf_counter() - t0
             # candidates tied at float level may swap argmins between the
             # paths; the two profiles at either argmin must still agree

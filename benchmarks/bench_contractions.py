@@ -9,8 +9,8 @@ prediction-cost fraction (the paper: merely a fraction of a contraction's
 runtime).
 
 Smoke mode (the CI lane): the batched spec at i=j=k=64 — the ``tc_rank64_*``
-metrics CI tracks across commits: suite cost, rank time on both engine
-backends, and the suite cost as a fraction of one measured contraction
+metrics CI tracks across commits: suite cost, rank time, and the suite
+cost as a fraction of one measured contraction
 execution (a pinned representative candidate, executed once, so the
 denominator's identity cannot drift with the ranking).  A second smoke
 section exercises size-sweep autotuning (``tc_sweep_*``): the same
@@ -107,11 +107,8 @@ def _run_smoke(report: List[str], results: Dict[str, object]) -> None:
     t_suite = pred.suite.cost_seconds
     n_batched = sum(is_batched_kernel(a.kernel) for a in pred.algorithms)
 
-    ranked_np = pred.rank(backend="numpy")          # engine + compile warmup
-    t_np = _best_of(lambda: pred.rank(backend="numpy"), 3)
-    ranked_jax = pred.rank(backend="jax")
-    t_jax = _best_of(lambda: pred.rank(backend="jax"), 3)
-    backend_agree = [r.name for r in ranked_np] == [r.name for r in ranked_jax]
+    ranked_np = pred.rank()                         # engine + compile warmup
+    t_np = _best_of(pred.rank, 3)
 
     # the per-algorithm scalar oracle on the SAME measurements: isolates the
     # engine-vs-scalar arithmetic, so the whole ordering must agree exactly
@@ -137,8 +134,7 @@ def _run_smoke(report: List[str], results: Dict[str, object]) -> None:
         f"algs={len(pred.algorithms)} (batched {n_batched}) "
         f"benchmarks={pred.n_benchmarks} suite={t_suite:5.2f}s")
     report.append(
-        f"  rank: numpy={t_np * 1e3:6.2f}ms jax={t_jax * 1e3:6.2f}ms "
-        f"backends {'==' if backend_agree else '!='} "
+        f"  rank: numpy={t_np * 1e3:6.2f}ms "
         f"oracle {'==' if oracle_agree else '!='} "
         f"winner={ranked_np[0].name}")
     report.append(
@@ -151,8 +147,6 @@ def _run_smoke(report: List[str], results: Dict[str, object]) -> None:
         "tc_rank64_benchmarks": pred.n_benchmarks,
         "tc_rank64_suite_s": t_suite,
         "tc_rank64_rank_numpy_s": t_np,
-        "tc_rank64_rank_jax_s": t_jax,
-        "tc_rank64_backend_agree": bool(backend_agree),
         "tc_rank64_oracle_agree": bool(oracle_agree),
         "tc_rank64_exec_s": t_exec,
         "tc_rank64_cost_frac": fraction,
@@ -165,11 +159,7 @@ def _run_smoke(report: List[str], results: Dict[str, object]) -> None:
     before = pred.suite.counters()
     sweep = sess.rank_contraction_sweep(spec, SWEEP_GRID)
     added = pred.suite.counters()
-    t_sweep_np = _best_of(lambda: [p.rank(backend="numpy")
-                                   for p in sweep.predictors], 3)
-    [p.rank(backend="jax") for p in sweep.predictors]   # compile warmup
-    t_sweep_jax = _best_of(lambda: [p.rank(backend="jax")
-                                    for p in sweep.predictors], 3)
+    t_sweep_np = _best_of(lambda: [p.rank() for p in sweep.predictors], 3)
     new_benchmarks = int(added["n_benchmarks"] - before["n_benchmarks"])
     # the pinned execution above is the denominator: the TOTAL suite cost
     # (single-size ranking + whole sweep) must stay a fraction of ONE run
@@ -180,7 +170,6 @@ def _run_smoke(report: List[str], results: Dict[str, object]) -> None:
         f"(total {sweep.n_benchmarks}) suite={sweep.suite.cost_seconds:5.2f}s")
     report.append(
         f"  rank all points: numpy={t_sweep_np * 1e3:6.2f}ms "
-        f"jax={t_sweep_jax * 1e3:6.2f}ms "
         f"winners={'|'.join(w.name[:24] for w in sweep.winners)} -> "
         f"total suite cost fraction {sweep_fraction:5.3f} "
         f"({'<' if sweep_fraction < 0.25 else '>='} 0.25 target)")
@@ -190,7 +179,6 @@ def _run_smoke(report: List[str], results: Dict[str, object]) -> None:
         "tc_sweep_benchmarks": sweep.n_benchmarks,
         "tc_sweep_suite_s": sweep.suite.cost_seconds,
         "tc_sweep_rank_numpy_s": t_sweep_np,
-        "tc_sweep_rank_jax_s": t_sweep_jax,
         "tc_sweep_cost_frac": sweep_fraction,
     })
 

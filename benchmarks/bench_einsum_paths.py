@@ -17,12 +17,12 @@ micro-benchmark IS a step execution (cost fraction -> the repetition
 count, never "a fraction"), and each extra kernel class costs one XLA
 compile per distinct signature — the full-mode run keeps the complete
 set.  The ``tc_chain_*`` metrics CI tracks across commits: suite cost,
-path-rank time on both engine backends, backend and oracle agreement on
-the top-ranked path, and the suite cost as a fraction of ONE execution
-of the chosen chain (< 0.25 required).  A ``tc_sweep_chain_*`` section
-re-ranks the same chain across three values of ``a`` from the SAME
-suite — size-sweep autotuning at the einsum-path level, with the total
-suite cost still a fraction of one chosen-chain execution.
+path-rank time, oracle agreement on the top-ranked path, and the suite
+cost as a fraction of ONE execution of the chosen chain (< 0.25
+required).  A ``tc_sweep_chain_*`` section re-ranks the same chain across
+three values of ``a`` from the SAME suite — size-sweep autotuning at the
+einsum-path level, with the total suite cost still a fraction of one
+chosen-chain execution.
 """
 
 from __future__ import annotations
@@ -103,13 +103,9 @@ def _run_smoke(report: List[str], results: Dict[str, object]) -> None:
                                 kernels=SMOKE_KERNELS,
                                 max_loop_perms=SMOKE_LOOP_PERMS,
                                 memory_limit_bytes=SMOKE_LIMIT)
-    ranked_np = pred.rank_paths(backend="numpy")    # suite runs here once
+    ranked_np = pred.rank_paths()                   # suite runs here once
     t_suite = pred.suite.cost_seconds
-    t_np = _best_of(lambda: pred.rank_paths(backend="numpy"), 3)
-    ranked_jax = pred.rank_paths(backend="jax")
-    t_jax = _best_of(lambda: pred.rank_paths(backend="jax"), 3)
-    backend_agree = [r.name for r in ranked_np] == \
-        [r.name for r in ranked_jax]
+    t_np = _best_of(pred.rank_paths, 3)
 
     # the step-by-step per-algorithm scalar oracle on the SAME measurements
     # (fresh=True would re-measure: only top-1 agreement would be noise-
@@ -132,8 +128,7 @@ def _run_smoke(report: List[str], results: Dict[str, object]) -> None:
         f"paths={len(pred.paths)} steps={n_steps} "
         f"benchmarks={pred.n_benchmarks} suite={t_suite:5.2f}s")
     report.append(
-        f"  rank: numpy={t_np * 1e3:6.2f}ms jax={t_jax * 1e3:6.2f}ms "
-        f"backends {'==' if backend_agree else '!='} "
+        f"  rank: numpy={t_np * 1e3:6.2f}ms "
         f"oracle-top {'==' if oracle_top_agree else '!='} "
         f"winner={best.name} "
         f"steps={'|'.join(s.name[:24] for s in best.steps)}")
@@ -146,8 +141,6 @@ def _run_smoke(report: List[str], results: Dict[str, object]) -> None:
         "tc_chain_benchmarks": pred.n_benchmarks,
         "tc_chain_suite_s": t_suite,
         "tc_chain_rank_numpy_s": t_np,
-        "tc_chain_rank_jax_s": t_jax,
-        "tc_chain_backend_agree": bool(backend_agree),
         "tc_chain_oracle_agree": bool(oracle_top_agree),
         "tc_chain_exec_s": t_exec,
         "tc_chain_cost_frac": fraction,

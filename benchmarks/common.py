@@ -179,8 +179,8 @@ def synthetic_model_set(specs=DEFAULT_SPECS,
 
 def catalog_synthetic_model_set(n: int = 264, b: int = 56) -> ModelSet:
     """Synthetic models covering every (kernel, case) the full tracer catalog
-    (``repro.dla.tracers.ALL_TRACERS``) emits — the model set the backend
-    equivalence tests sweep the whole catalog against."""
+    (``repro.dla.tracers.ALL_TRACERS``) emits — the model set the
+    engine-vs-scalar equivalence tests sweep the whole catalog against."""
     from repro.dla.tracers import required_kernel_cases
 
     dims: Dict[str, int] = {}

@@ -13,10 +13,9 @@ are flagged for a human, not hard-failed).  Unreadable artifacts are also
 only warned about.
 
 Besides cross-commit trends, the CURRENT artifact alone is checked for
-backend inversions: the fused jax path must not be slower than its numpy
-counterpart (the original motivation for fusing the engine into one XLA
-program), so every ``BACKEND_RATIOS`` pair warns when jax > numpy —
-also when no previous artifact exists (pass ``-`` as PREVIOUS).
+model-guided serving falling below its FIFO baseline
+(:data:`SERVING_RATIOS`) — also when no previous artifact exists (pass
+``-`` as PREVIOUS).
 
 One check DOES fail the job: a metric the current artifact carries that
 neither :data:`METRICS` nor :data:`UNTRACKED` lists.  A number computed
@@ -37,20 +36,17 @@ import json
 #: comparison ratio (a drop below 1/threshold warns).  reprolint's
 #: metric-tracking checker parses this literal, so keep it constants-only.
 METRICS = (
-    ("batched_sweep", "sweep64_jax_cached_s", False),
     ("batched_sweep", "sweep64_numpy_s", False),
     ("batched_sweep", "sweep64_numpy_cached_s", False),
     ("batched_sweep", "sweep_batched_s", False),
     ("batched_sweep", "grid_s", False),
     ("contractions", "tc_rank64_suite_s", False),
     ("contractions", "tc_rank64_rank_numpy_s", False),
-    ("contractions", "tc_rank64_rank_jax_s", False),
     ("contractions", "tc_sweep_suite_s", False),
-    ("contractions", "tc_sweep_rank_jax_s", False),
+    ("contractions", "tc_sweep_rank_numpy_s", False),
     ("contractions", "tc_param_refine_suite_s", False),
     ("einsum_paths", "tc_chain_suite_s", False),
     ("einsum_paths", "tc_chain_rank_numpy_s", False),
-    ("einsum_paths", "tc_chain_rank_jax_s", False),
     ("serving", "serve_p99_ms", False),
     ("serving", "serve_tick_overhead_ms", False),
     ("serving", "serve_goodput_tok_s", True),
@@ -86,25 +82,17 @@ UNTRACKED = (
     # trending a 0/1 across commits adds nothing
     ("batched_sweep", "argmin_agree"),
     ("batched_sweep", "rank_order_agree"),
-    ("batched_sweep", "sweep64_jax_beats_numpy"),
-    ("contractions", "tc_rank64_backend_agree"),
     ("contractions", "tc_rank64_oracle_agree"),
     ("contractions", "tc_param_top1_agree"),
-    ("einsum_paths", "tc_chain_backend_agree"),
     ("einsum_paths", "tc_chain_oracle_agree"),
     # numerical-agreement magnitudes: bounded by in-bench assertions
     ("batched_sweep", "max_rel_diff"),
-    ("batched_sweep", "max_rel_backend_diff"),
-    ("batched_sweep", "max_rel_fused_diff"),
     # scalar-path / one-shot reference timings and derived speedups: the
     # slow side of a ratio whose fast side is already trended above
     ("batched_sweep", "sweep_scalar_s"),
     ("batched_sweep", "sweep_speedup"),
     ("batched_sweep", "rank_scalar_s"),
     ("batched_sweep", "rank_batched_s"),
-    ("batched_sweep", "sweep64_jax_grouped_s"),
-    ("batched_sweep", "sweep64_fused_speedup"),
-    ("batched_sweep", "sweep64_speedup"),
     # single-execution denominators and their cost fractions: one real
     # kernel execution each — too noisy on shared runners to trend
     ("contractions", "tc_rank64_exec_s"),
@@ -178,16 +166,6 @@ SERVING_RATIOS = (
     ("serving", "serve_goodput_tok_s", "serve_fifo_goodput_tok_s"),
 )
 
-#: (suite, jax metric, numpy metric) pairs checked WITHIN one artifact:
-#: a jax path slower than its numpy counterpart is a regression of the
-#: fused engine and warns on every PR
-BACKEND_RATIOS = (
-    ("batched_sweep", "sweep64_jax_cached_s", "sweep64_numpy_cached_s"),
-    ("contractions", "tc_rank64_rank_jax_s", "tc_rank64_rank_numpy_s"),
-    ("contractions", "tc_sweep_rank_jax_s", "tc_sweep_rank_numpy_s"),
-    ("einsum_paths", "tc_chain_rank_jax_s", "tc_chain_rank_numpy_s"),
-)
-
 
 def _metric(artifact: dict, suite: str, name: str):
     value = artifact.get("suites", {}).get(suite, {}).get("metrics",
@@ -217,28 +195,6 @@ def compare(prev: dict, curr: dict, threshold: float) -> int:
                   f"{direction} {ratio:.2f}x ({old:.4g} -> {new:.4g}, "
                   f"threshold {threshold}x)")
         print(line)
-    return flagged
-
-
-def check_backend_ratios(curr: dict) -> int:
-    """Warn on jax-slower-than-numpy inversions in ONE artifact."""
-    flagged = 0
-    for suite, jax_name, numpy_name in BACKEND_RATIOS:
-        t_jax = _metric(curr, suite, jax_name)
-        t_np = _metric(curr, suite, numpy_name)
-        if t_jax is None or t_np is None or t_np <= 0:
-            print(f"  {suite}.{jax_name} vs {numpy_name}: not comparable "
-                  f"(jax={t_jax!r} numpy={t_np!r})")
-            continue
-        ratio = t_jax / t_np
-        if ratio > 1.0:
-            flagged += 1
-            print(f"::warning title=jax backend slower than numpy::"
-                  f"{suite}.{jax_name} = {t_jax * 1e3:.2f}ms > "
-                  f"{suite}.{numpy_name} = {t_np * 1e3:.2f}ms "
-                  f"({ratio:.2f}x) — the fused jax path should win")
-        print(f"  {suite}.{jax_name}: {t_jax * 1e3:.2f}ms vs "
-              f"{numpy_name}: {t_np * 1e3:.2f}ms ({ratio:.2f}x)")
     return flagged
 
 
@@ -274,7 +230,7 @@ def check_tracking(curr: dict) -> int:
     as known: they are consumed within one artifact, not trended.
     """
     known = set(TRACKED) | set(UNTRACKED)
-    for suite, a, b in BACKEND_RATIOS + SERVING_RATIOS:
+    for suite, a, b in SERVING_RATIOS:
         known.update({(suite, a), (suite, b)})
     unknown = 0
     for suite, payload in curr.get("suites", {}).items():
@@ -293,7 +249,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("previous",
                     help="previous BENCH_smoke.json ('-' when none exists: "
-                         "only the current artifact's backend ratios are "
+                         "only the current artifact's serving ratios are "
                          "checked)")
     ap.add_argument("current", help="current BENCH_smoke.json")
     ap.add_argument("--threshold", type=float, default=1.5,
@@ -318,8 +274,6 @@ def main() -> None:
         flagged += compare(prev, curr, args.threshold)
     else:
         print("no previous artifact; cross-commit comparison skipped")
-    print("backend ratios (jax must not be slower than numpy):")
-    flagged += check_backend_ratios(curr)
     print("serving ratios (model-guided must not lose to FIFO):")
     flagged += check_serving_ratios(curr)
     print(f"{flagged} regression(s) flagged" if flagged

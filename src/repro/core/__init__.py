@@ -13,9 +13,9 @@ from .grids import Domain, grid_points
 from .model import CaseModel, ModelSet, PerformanceModel, Piece
 from .modelgen import (GenerationReport, KernelBenchmark, generate_model,
                        generate_model_set)
-from .predict import (BACKENDS, CompiledCalls, FusedBatch, KernelCall,
-                      PredictionEngine, TraceCache, absolute_relative_error,
-                      compile_calls, predict_efficiency, predict_performance,
+from .predict import (CompiledCalls, KernelCall, PredictionEngine,
+                      TraceCache, absolute_relative_error, compile_calls,
+                      predict_efficiency, predict_performance,
                       predict_runtime, relative_error)
 from .refinement import GeneratorConfig, refine, stats_sample_fn
 from .sampler import STATS, Stats, measure_calls, measure_single
@@ -31,9 +31,8 @@ __all__ = [
     "monomial_basis", "relative_errors", "stack_polynomials", "Domain",
     "grid_points", "CaseModel", "ModelSet",
     "PerformanceModel", "Piece", "GenerationReport", "KernelBenchmark",
-    "generate_model", "generate_model_set", "BACKENDS", "CompiledCalls",
-    "FusedBatch", "KernelCall", "PredictionEngine", "TraceCache",
-    "compile_calls",
+    "generate_model", "generate_model_set", "CompiledCalls", "KernelCall",
+    "PredictionEngine", "TraceCache", "compile_calls",
     "absolute_relative_error", "predict_efficiency", "predict_performance",
     "predict_runtime", "relative_error", "GeneratorConfig", "refine",
     "stats_sample_fn", "STATS", "Stats", "measure_calls", "measure_single",

@@ -401,7 +401,7 @@ def predict_contraction(alg: ContractionAlgorithm,
     return total
 
 
-def _session_for(fn: str, session, *, backend=None, suite=None, cache=None,
+def _session_for(fn: str, session, *, suite=None, cache=None,
                  repetitions=None, extra_deprecated=None):
     """The one shim implementation behind every legacy entry point.
 
@@ -413,8 +413,8 @@ def _session_for(fn: str, session, *, backend=None, suite=None, cache=None,
     """
     from ..tc.session import (PredictorSession,  # lazy: tc builds on core
                               warn_deprecated_kwargs)
-    legacy = {"backend": backend, "suite": suite, "cache": cache,
-              "repetitions": repetitions, **(extra_deprecated or {})}
+    legacy = {"suite": suite, "cache": cache, "repetitions": repetitions,
+              **(extra_deprecated or {})}
     if session is not None:
         used = [k for k, v in legacy.items() if v is not None]
         if used:
@@ -425,8 +425,8 @@ def _session_for(fn: str, session, *, backend=None, suite=None, cache=None,
         return session
     warn_deprecated_kwargs(fn, "the session's methods", legacy,
                            stacklevel=4)
-    return PredictorSession(backend=backend or "numpy", suite=suite,
-                            cache=cache, repetitions=repetitions)
+    return PredictorSession(suite=suite, cache=cache,
+                            repetitions=repetitions)
 
 
 def rank_contraction_algorithms(spec: ContractionSpec,
@@ -436,7 +436,6 @@ def rank_contraction_algorithms(spec: ContractionSpec,
                                 repetitions: Optional[int] = None,
                                 stat: str = "med",
                                 batched: bool = True,
-                                backend: Optional[str] = None,
                                 suite=None,
                                 cache=None,
                                 sizes_grid: Optional[Sequence[
@@ -453,11 +452,11 @@ def rank_contraction_algorithms(spec: ContractionSpec,
     is not given) shares one deduplicated micro-benchmark suite and is
     predicted through the batched :class:`PredictionEngine`.  Pass
     ``session=`` (a :class:`repro.tc.PredictorSession`) to share its
-    suite, trace cache and backend across calls — the sprawl of
-    per-call ``backend=``/``suite=``/``cache=``/``repetitions=``/
-    ``sizes_grid=`` keywords is DEPRECATED in favor of the session and
-    its methods (one release of shim support: they still work, warning,
-    by constructing a session internally).  ``batched=False`` keeps the
+    suite and trace cache across calls — the sprawl of per-call
+    ``suite=``/``cache=``/``repetitions=``/``sizes_grid=`` keywords is
+    DEPRECATED in favor of the session and its methods (one release of
+    shim support: they still work, warning, by constructing a session
+    internally).  ``batched=False`` keeps the
     original per-algorithm path — one independent micro-benchmark per
     candidate — as the equivalence oracle.
 
@@ -478,7 +477,7 @@ def rank_contraction_algorithms(spec: ContractionSpec,
                              "the scalar oracle (batched=False) has no "
                              "size-sweep mode")
         sess = _session_for("rank_contraction_algorithms", session,
-                            backend=backend, suite=suite, cache=cache,
+                            suite=suite, cache=cache,
                             repetitions=repetitions,
                             extra_deprecated={"sizes_grid": sizes_grid})
         sweep = sess.rank_contraction_sweep(spec, sizes_grid, stat=stat,
@@ -490,13 +489,13 @@ def rank_contraction_algorithms(spec: ContractionSpec,
                          "size-sweep mode)")
     if batched:
         sess = _session_for("rank_contraction_algorithms", session,
-                            backend=backend, suite=suite, cache=cache,
+                            suite=suite, cache=cache,
                             repetitions=repetitions)
         ranked = sess.rank_contraction_algorithms(spec, sizes, stat=stat,
                                                   algorithms=algorithms)
         return [(r.algorithm, getattr(r.runtime, stat)) for r in ranked]
-    if backend is not None or suite is not None or cache is not None:
-        raise ValueError("backend=/suite=/cache= apply to the batched "
+    if suite is not None or cache is not None:
+        raise ValueError("suite=/cache= apply to the batched "
                          "predictor; the scalar oracle (batched=False) has "
                          "none of them")
     if session is not None:

@@ -97,12 +97,6 @@ class StackedPolynomials:
     Heterogeneous bases (e.g. a constant-only std polynomial next to full
     cost-bounded stat polynomials) fall into separate groups and still
     evaluate with one design matrix per group, not one per polynomial.
-
-    Besides the numpy path (``__call__``), :meth:`flattened` exports the
-    groups as dense per-row tensors — the form the prediction engine's
-    ``backend="jax"`` path pads and gathers per (kernel, case) — and
-    :meth:`eval_jax` evaluates them standalone in one ``jax.jit``-compiled
-    float64 program (same :func:`monomials_jnp` core as the engine path).
     """
 
     #: per group: (basis, scale, coeff matrix (M, k), output column indices)
@@ -118,75 +112,6 @@ class StackedPolynomials:
             X = _design_matrix(pts, basis, scale)
             out[:, cols] = X @ coeff_mat
         return out
-
-    def flattened(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """All groups merged into per-row dense tensors for the JAX path.
-
-        Returns ``(exps (M, d), scale (M, d), coeffs (M, n_out))`` where row
-        ``m`` contributes ``coeffs[m, j] * prod_d (x_d / scale[m, d]) **
-        exps[m, d]`` to output column ``j``.  Carrying the normalization per
-        row keeps the evaluation bit-for-bit equivalent in structure to the
-        grouped numpy path, and zero-padded rows (exponent 0, coefficient 0)
-        contribute exactly nothing — so flattened tensors of different
-        stacks can be padded to a common width and batched together.
-        """
-        cached = self.__dict__.get("_flattened_cache")
-        if cached is None:
-            exps, scl, cof = [], [], []
-            for basis, scale, coeff_mat, cols in self.groups:
-                for r, e in enumerate(basis):
-                    exps.append(e)
-                    scl.append(scale)
-                    row = np.zeros(self.n_out, dtype=np.float64)
-                    row[list(cols)] = coeff_mat[r]
-                    cof.append(row)
-            cached = (np.asarray(exps, dtype=np.float64),
-                      np.asarray(scl, dtype=np.float64),
-                      np.stack(cof))
-            object.__setattr__(self, "_flattened_cache", cached)
-        return cached
-
-    def eval_jax(self, points) -> np.ndarray:
-        """JAX-jitted equivalent of ``__call__`` (float64, agrees ~1e-8)."""
-        pts = np.atleast_2d(np.asarray(points, dtype=np.float64))
-        return np.asarray(jax_eval_flattened(pts, *self.flattened()))
-
-
-# ------------------------------------------------------------ JAX backend --
-#
-# jax is imported lazily so the numpy-only fitting/prediction path never
-# pays for (or depends on) an accelerator runtime import.
-
-_JAX_EVAL = None
-
-
-def monomials_jnp(pts, exps, scl):
-    """``X[..., n, m] = prod_d (pts[n, d] / scl[..., m, d]) ** exps[..., m, d]``.
-
-    The one jnp implementation of the normalized design matrix, shared by
-    every jitted evaluation path: ``exps``/``scl`` may be ``(M, d)`` (one
-    polynomial stack for all points) or ``(N, M, d)`` (per-point gathered
-    tensors, as in the model layer's fused piece lookup).
-    """
-    import jax.numpy as jnp
-
-    return jnp.prod((pts[:, None, :] / scl) ** exps, axis=-1)
-
-
-def _eval_flattened_impl(pts, exps, scl, cof):
-    # pts (N, d); exps/scl (M, d); cof (M, n_out)
-    return monomials_jnp(pts, exps, scl) @ cof              # (N, n_out)
-
-
-def jax_eval_flattened(pts, exps, scl, cof):
-    """Evaluate flattened polynomial tensors under jit, in float64."""
-    global _JAX_EVAL
-    import jax
-
-    if _JAX_EVAL is None:
-        _JAX_EVAL = jax.jit(_eval_flattened_impl)
-    with jax.enable_x64():
-        return _JAX_EVAL(pts, exps, scl, cof)
 
 
 def stack_polynomials(polys: Sequence[Polynomial]) -> StackedPolynomials:

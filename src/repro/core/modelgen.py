@@ -58,11 +58,8 @@ def generate_model(bench: KernelBenchmark,
 
     For every case of ``bench``, measures the kernel over adaptively
     refined sub-domains (:func:`~repro.core.refinement.refine` under
-    ``config``) and fits piecewise polynomials.  The model is returned
-    *finalized*: every case's padded piece tensors (the dense form the
-    fused prediction engine gathers from) are emitted here, as part of
-    generation, instead of being re-derived on the first predict.
-    Returns the :class:`~repro.core.model.PerformanceModel` plus a
+    ``config``) and fits piecewise polynomials.  Returns the
+    :class:`~repro.core.model.PerformanceModel` plus a
     :class:`GenerationReport` with the measured-point count, pieces per
     case and wall-clock seconds.
     """
@@ -88,7 +85,6 @@ def generate_model(bench: KernelBenchmark,
             model.add_piece(case, piece)
         pieces_per_case[case] = len(pieces)
         total_points += counted[0]
-    model.finalize()
     report = GenerationReport(
         kernel=bench.name,
         seconds=time.perf_counter() - t0,

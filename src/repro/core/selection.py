@@ -7,12 +7,11 @@ executing any of them.  Block-size optimization evaluates the prediction over
 a candidate grid of b and returns the argmin plus the whole profile (used to
 compute the paper's "performance yield" against empirical optima).
 
-Both entry points run on the vectorized :class:`PredictionEngine` by default
-(the batch of candidate configurations is predicted with a handful of array
-ops); pass ``batched=False`` to fall back to the scalar per-call reference
-path, which is kept as the equivalence oracle.  ``backend="jax"`` evaluates
-the stacked polynomials in jitted XLA programs.  Repeated selections reuse
-a trace cache (traced call sequences and each compiled candidate batch)
+Every entry point runs on the vectorized :class:`PredictionEngine` (the
+batch of candidate configurations is predicted with a handful of array
+ops); the scalar per-call :func:`~repro.core.predict.predict_runtime` is
+its equivalence oracle, which tests call directly.  Repeated selections
+reuse a trace cache (traced call sequences and each compiled candidate batch)
 instead of re-tracing: the ``engine=``'s own, or without one a bounded
 cache shared by every engine built for a call that passed none.  Either
 way each selection evaluates every candidate's models anew.
@@ -28,8 +27,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 from .model import ModelSet
-from .predict import (PredictionEngine, Tracer, predict_runtime,
-                      resolve_engine)
+from .predict import PredictionEngine, Tracer, resolve_engine
 from .sampler import STATS, Stats
 
 
@@ -43,64 +41,39 @@ class RankedAlgorithm:
     block_size: int
 
 
-def _check_scalar_path(batched, backend, engine):
-    if not batched and (backend is not None or engine is not None):
-        raise ValueError("backend=/engine= apply to the batched engine; "
-                         "the scalar oracle (batched=False) has neither")
-
-
 def rank_algorithms(tracers: Mapping[str, Tracer], models: ModelSet,
-                    n: int, b: int, *,
-                    stat: str = "med", batched: bool = True,
-                    backend: Optional[str] = None,
+                    n: int, b: int, *, stat: str = "med",
                     engine: Optional[PredictionEngine] = None,
                     ) -> List[RankedAlgorithm]:
     """Predict every variant's runtime and sort ascending (§4.5)."""
-    _check_scalar_path(batched, backend, engine)
     names = list(tracers)
-    if batched:
-        eng = resolve_engine(models, backend, engine)
-        compiled = eng.cache.compiled_selection(
-            [tracers[name] for name in names], n, [b])
-        runtimes = [Stats(*map(float, row))
-                    for row in eng.predict_compiled(compiled)]
-    else:
-        runtimes = [predict_runtime(tracers[name](n, b), models)
-                    for name in names]
-    ranked = [RankedAlgorithm(name=name, runtime=rt, block_size=b)
-              for name, rt in zip(names, runtimes)]
+    eng = resolve_engine(models, engine)
+    compiled = eng.cache.compiled_selection(
+        [tracers[name] for name in names], n, [b])
+    ranked = [RankedAlgorithm(name=name, runtime=Stats(*map(float, row)),
+                              block_size=b)
+              for name, row in zip(names, eng.predict_compiled(compiled))]
     ranked.sort(key=lambda r: getattr(r.runtime, stat))
     return ranked
 
 
 def select_algorithm(tracers: Mapping[str, Tracer], models: ModelSet,
                      n: int, b: int, *, stat: str = "med",
-                     batched: bool = True, backend: Optional[str] = None,
                      engine: Optional[PredictionEngine] = None) -> str:
     """The name of the variant with the fastest predicted runtime —
     ``rank_algorithms(...)[0].name``, same keywords."""
-    return rank_algorithms(tracers, models, n, b, stat=stat, batched=batched,
-                           backend=backend, engine=engine)[0].name
+    return rank_algorithms(tracers, models, n, b, stat=stat,
+                           engine=engine)[0].name
 
 
 def optimize_block_size(tracer: Tracer, models: ModelSet, n: int,
-                        candidates: Sequence[int], *,
-                        stat: str = "med", batched: bool = True,
-                        backend: Optional[str] = None,
+                        candidates: Sequence[int], *, stat: str = "med",
                         engine: Optional[PredictionEngine] = None,
                         ) -> Tuple[int, Dict[int, float]]:
     """b_pred = argmin_b t_pred(n, b) over the candidate grid (§4.6)."""
-    _check_scalar_path(batched, backend, engine)
-    if batched:
-        eng = resolve_engine(models, backend, engine)
-        col = STATS.index(stat)
-        vals = eng.sweep(tracer, n, candidates)[:, col]
-        profile = {b: float(v) for b, v in zip(candidates, vals)}
-    else:
-        profile = {
-            b: getattr(predict_runtime(tracer(n, b), models), stat)
-            for b in candidates
-        }
+    eng = resolve_engine(models, engine)
+    vals = eng.sweep(tracer, n, candidates)[:, STATS.index(stat)]
+    profile = {b: float(v) for b, v in zip(candidates, vals)}
     b_pred = min(profile, key=profile.get)
     return b_pred, profile
 
@@ -108,39 +81,25 @@ def optimize_block_size(tracer: Tracer, models: ModelSet, n: int,
 def optimize_algorithm_and_block_size(
         tracers: Mapping[str, Tracer], models: ModelSet, n: int,
         candidates: Sequence[int], *, stat: str = "med",
-        batched: bool = True, backend: Optional[str] = None,
         engine: Optional[PredictionEngine] = None,
 ) -> Tuple[str, int, float]:
-    """Joint variant + block-size selection: the paper's two goals combined."""
-    _check_scalar_path(batched, backend, engine)
-    if batched:
-        # one compiled batch over the whole variants x candidates grid;
-        # np.argmin's first-minimum tie-breaking matches the scalar loop
-        eng = resolve_engine(models, backend, engine)
-        names = list(tracers)
-        col = STATS.index(stat)
-        vals = eng.predict_compiled(eng.cache.compiled_selection(
-            [tracers[name] for name in names], n, candidates))
-        grid = vals[:, col].reshape(len(names), len(candidates))
-        flat = int(grid.argmin())
-        vi, bi = divmod(flat, len(candidates))
-        return names[vi], candidates[bi], float(grid[vi, bi])
-    best: Optional[Tuple[str, int, float]] = None
-    for name, tracer in tracers.items():
-        b, profile = optimize_block_size(tracer, models, n, candidates,
-                                         stat=stat, batched=False)
-        t = profile[b]
-        if best is None or t < best[2]:
-            best = (name, b, t)
-    assert best is not None
-    return best
+    """Joint variant + block-size selection: the paper's two goals combined.
+
+    One compiled batch over the whole variants x candidates grid; the
+    first minimum wins ties, in tracer then candidate order."""
+    eng = resolve_engine(models, engine)
+    names = list(tracers)
+    vals = eng.predict_compiled(eng.cache.compiled_selection(
+        [tracers[name] for name in names], n, candidates))
+    grid = vals[:, STATS.index(stat)].reshape(len(names), len(candidates))
+    vi, bi = divmod(int(grid.argmin()), len(candidates))
+    return names[vi], candidates[bi], float(grid[vi, bi])
 
 
 # ------------------------------------------------- contractions (Ch. 6) --
 
 def select_contraction_algorithm(spec, sizes: Mapping[str, int], *,
                                  stat: str = "med",
-                                 backend: Optional[str] = None,
                                  repetitions: Optional[int] = None,
                                  predictor=None, session=None) -> str:
     """Ch. 6 counterpart of :func:`select_algorithm`: the contraction
@@ -152,8 +111,8 @@ def select_contraction_algorithm(spec, sizes: Mapping[str, int], *,
     :class:`PredictionEngine` the blocked-algorithm entry points use.
     Pass ``session=`` (a :class:`repro.tc.PredictorSession`) to share its
     suite measurements and compiled batches across calls; the per-call
-    ``backend=``/``repetitions=``/``predictor=`` keywords are DEPRECATED
-    in favor of the session (one release of shim support).
+    ``repetitions=``/``predictor=`` keywords are DEPRECATED in favor of
+    the session (one release of shim support).
     """
     from ..tc.session import warn_deprecated_kwargs  # lazy: tc needs core
     from .contractions import ContractionSpec, _session_for
@@ -177,10 +136,10 @@ def select_contraction_algorithm(spec, sizes: Mapping[str, int], *,
             "select_contraction_algorithm",
             "session.select_contraction_algorithm (the session memoizes "
             "the predictor)",
-            {"predictor": predictor, "backend": backend})
-        return predictor.rank(stat=stat, backend=backend or "numpy")[0].name
+            {"predictor": predictor})
+        return predictor.rank(stat=stat)[0].name
     sess = _session_for("select_contraction_algorithm", session,
-                        backend=backend, repetitions=repetitions)
+                        repetitions=repetitions)
     return sess.select_contraction_algorithm(spec, sizes, stat=stat)
 
 
@@ -204,7 +163,6 @@ def _resolve_chain_predictor(chain, sizes, repetitions, predictor):
 
 def rank_einsum_paths(chain, sizes: Optional[Mapping[str, int]] = None, *,
                       stat: str = "med",
-                      backend: Optional[str] = None,
                       repetitions: Optional[int] = None,
                       predictor=None,
                       sizes_grid: Optional[Sequence[
@@ -215,10 +173,9 @@ def rank_einsum_paths(chain, sizes: Optional[Mapping[str, int]] = None, *,
     The chain counterpart of :func:`rank_algorithms`: all candidate paths
     (``chain`` is a :class:`repro.tc.ChainSpec` or an expression like
     ``"ij,jk,kl->il"``) are predicted through one shared deduplicated
-    micro-benchmark suite and the batched engine
-    (``backend="numpy"|"jax"``) and returned fastest-first as
-    :class:`repro.tc.RankedChain` records — per-step winning algorithms
-    included.  Pass ``predictor=`` (a :class:`repro.tc.ChainPredictor`)
+    micro-benchmark suite and the batched engine and returned
+    fastest-first as :class:`repro.tc.RankedChain` records — per-step
+    winning algorithms included.  Pass ``predictor=`` (a :class:`repro.tc.ChainPredictor`)
     to reuse measurements and compiled batches across calls; the
     step-by-step per-algorithm oracle remains available on the predictor
     as :meth:`~repro.tc.ChainPredictor.rank_paths_oracle`.
@@ -229,10 +186,10 @@ def rank_einsum_paths(chain, sizes: Optional[Mapping[str, int]] = None, *,
     the genuinely new micro-benchmark keys are measured.
 
     Pass ``session=`` (a :class:`repro.tc.PredictorSession`) to share its
-    suite, trace cache and backend across calls; the per-call
-    ``backend=``/``repetitions=``/``predictor=``/``suite=``/``cache=``/
-    ``sizes_grid=`` keywords are DEPRECATED in favor of the session and
-    its :meth:`~repro.tc.PredictorSession.rank_einsum_paths` /
+    suite and trace cache across calls; the per-call ``repetitions=``/
+    ``predictor=``/``suite=``/``cache=``/``sizes_grid=`` keywords are
+    DEPRECATED in favor of the session and its
+    :meth:`~repro.tc.PredictorSession.rank_einsum_paths` /
     :meth:`~repro.tc.PredictorSession.rank_einsum_sweep` methods (one
     release of shim support).
     """
@@ -243,9 +200,8 @@ def rank_einsum_paths(chain, sizes: Optional[Mapping[str, int]] = None, *,
             raise ValueError("sizes_grid= replaces sizes= and builds its "
                              "own per-point predictors; pass one mode or "
                              "the other")
-        sess = _session_for("rank_einsum_paths", session, backend=backend,
-                            suite=suite, cache=cache,
-                            repetitions=repetitions,
+        sess = _session_for("rank_einsum_paths", session, suite=suite,
+                            cache=cache, repetitions=repetitions,
                             extra_deprecated={"sizes_grid": sizes_grid})
         return list(sess.rank_einsum_sweep(chain, sizes_grid,
                                            stat=stat).rankings)
@@ -265,16 +221,15 @@ def rank_einsum_paths(chain, sizes: Optional[Mapping[str, int]] = None, *,
             "rank_einsum_paths",
             "session.rank_einsum_paths (the session memoizes the "
             "predictor)",
-            {"predictor": predictor, "backend": backend})
-        return pred.rank_paths(stat=stat, backend=backend or "numpy")
-    sess = _session_for("rank_einsum_paths", session, backend=backend,
+            {"predictor": predictor})
+        return pred.rank_paths(stat=stat)
+    sess = _session_for("rank_einsum_paths", session,
                         repetitions=repetitions)
     return sess.rank_einsum_paths(chain, sizes, stat=stat)
 
 
 def select_einsum_path(chain, sizes: Mapping[str, int], *,
                        stat: str = "med",
-                       backend: Optional[str] = None,
                        repetitions: Optional[int] = None,
                        predictor=None, session=None):
     """The fastest-predicted contraction path of an N-operand einsum.
@@ -288,7 +243,7 @@ def select_einsum_path(chain, sizes: Mapping[str, int], *,
     # shim plumbing: forwards the caller's own (possibly deprecated)
     # kwargs verbatim so the deprecation warning fires exactly once
     # reprolint: allow[deprecated-kwarg]
-    return rank_einsum_paths(chain, sizes, stat=stat, backend=backend,
+    return rank_einsum_paths(chain, sizes, stat=stat,
                              repetitions=repetitions,
                              predictor=predictor, session=session)[0]
 

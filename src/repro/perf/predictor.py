@@ -77,7 +77,6 @@ def rank_traced_configs(tracers: Mapping[str, Callable[..., List[KernelCall]]],
                         models: ModelSet,
                         *tracer_args,
                         stat: str = "med",
-                        backend: Optional[str] = None,
                         engine: Optional[PredictionEngine] = None,
                         ) -> List[RankedTracedConfig]:
     """Rank trace-producing candidates on the batched prediction engine.
@@ -86,14 +85,13 @@ def rank_traced_configs(tracers: Mapping[str, Callable[..., List[KernelCall]]],
     this path never compiles anything: each candidate's kernel-call trace is
     batched through :class:`PredictionEngine`, so sweeping hundreds of
     configurations costs a handful of array ops — the §4.5 selection applied
-    at config-sweep scale.  ``backend="jax"`` evaluates the models in jitted
-    XLA programs; ``engine=`` exists for symmetry with the core selection
-    entry points (jit caches are process-wide, and these tracers take
-    arbitrary ``*tracer_args``, so the per-(n, b) trace cache does not
-    apply — a shared engine buys consistency checks, not reuse).
+    at config-sweep scale.  ``engine=`` exists for symmetry with the core
+    selection entry points (these tracers take arbitrary
+    ``*tracer_args``, so the per-(n, b) trace cache does not apply — a
+    shared engine buys consistency checks, not reuse).
     """
     names = list(tracers)
-    engine = resolve_engine(models, backend, engine)
+    engine = resolve_engine(models, engine)
     runtimes = engine.predict_stats(
         [tracers[name](*tracer_args) for name in names])
     ranked = [RankedTracedConfig(name=name, runtime=rt, stat=stat)

@@ -3,7 +3,7 @@
 Three layers (see ``docs/model-store.md``):
 
 * :mod:`repro.store.modelstore` — versioned on-disk persistence of
-  micro-benchmark measurements and finalized model sets under a
+  micro-benchmark measurements and model sets under a
   :mod:`platform fingerprint <repro.store.fingerprint>`;
 * :mod:`repro.store.drift` — deterministic re-measurement probes that
   detect when a stored model has drifted off the platform;

@@ -4,7 +4,7 @@ A :class:`ModelStore` makes the paper's "once per platform" amortization
 real across processes: it serializes a
 :class:`~repro.tc.suite.MicroBenchmarkSuite`'s measurements (keyed by the
 canonical :class:`~repro.tc.suite.MicroBenchmarkKey` — equation, kernel
-shapes, per-operand cache classes), plus any finalized
+shapes, per-operand cache classes), plus any
 :class:`~repro.core.model.ModelSet` artifacts, under a
 :class:`~repro.store.fingerprint.PlatformFingerprint`.  A serve process
 or CI run warm-starts by loading the store into a fresh suite: every
@@ -115,14 +115,12 @@ def _finite(value: float, what: str) -> float:
 
 
 class ModelStore:
-    """Measurements + finalized model artifacts under one fingerprint.
+    """Measurements + model artifacts under one fingerprint.
 
     Build one with :meth:`from_suite` (capture a measured suite), extend
-    it with :meth:`add_model_set` (finalized per-signature or
-    generated-model artifacts), persist with :meth:`save`, and
-    reconstruct with :meth:`load` — which re-finalizes every model set so
-    the padded-tensor artifacts the fused engine gathers from are part
-    of the loaded object, not re-derived on first predict.
+    it with :meth:`add_model_set` (per-signature or generated-model
+    artifacts), persist with :meth:`save`, and reconstruct with
+    :meth:`load`.
     """
 
     def __init__(self, *,
@@ -169,7 +167,7 @@ class ModelStore:
             sum(mb.seconds for mb in self.measurements.values()))
 
     def add_model_set(self, name: str, models: ModelSet) -> None:
-        """Attach a finalized :class:`ModelSet` artifact under ``name``."""
+        """Attach a :class:`ModelSet` artifact under ``name``."""
         self.model_sets[name] = models
 
     def model_set(self, name: str) -> ModelSet:
@@ -317,8 +315,6 @@ class ModelStore:
                 key=key, stats=Stats(**entry["stats"]),
                 first=entry["first"], seconds=entry["seconds"])
         for name, ms in payload.get("model_sets", {}).items():
-            # from_dict re-finalizes: padded case tensors are part of the
-            # loaded artifact, exactly as ModelSet.finalize emitted them
             store.model_sets[name] = ModelSet.from_dict(ms)
         return store
 

@@ -1,8 +1,8 @@
 """Predictor tournaments: quantified accuracy-vs-cost scoreboards.
 
 Every change to the prediction stack shifts a trade-off: measurement
-protocol (repetitions, cache capacity), store freshness, backend, model
-form.  The tournament harness makes that trade-off a number instead of a
+protocol (repetitions, cache capacity), store freshness, model form.
+The tournament harness makes that trade-off a number instead of a
 hunch — it pits named predictor *snapshots* (a
 :class:`~repro.store.modelstore.ModelStore` file plus session config)
 against each other on **frozen workload suites** (the smoke specs from
@@ -139,7 +139,6 @@ class Snapshot:
 
     name: str
     store: Union[ModelStore, str, Path]
-    backend: str = "numpy"
 
     def open(self, *, allow_mismatch: bool = False,
              fingerprint=None) -> ModelStore:
@@ -276,7 +275,6 @@ def run_tournament(snapshots: Sequence[Snapshot],
         store = snap.open(allow_mismatch=allow_mismatch,
                           fingerprint=fingerprint)
         session = PredictorSession(
-            backend=snap.backend,
             suite=store.build_suite(measure_fn=measure_fn))
         scores.append(score_snapshot(snap.name, session, loads,
                                      oracle_rankings))

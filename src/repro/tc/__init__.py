@@ -11,8 +11,8 @@ per-step predictors into multi-contraction chain rankings with
 cache-state propagation between steps (:mod:`~repro.tc.chains`).
 
 Since the session redesign, :class:`~repro.tc.session.PredictorSession`
-is the single entry point: one object owning the shared suite, trace
-cache and backend, fronting every ranking/selection mode and the serving
+is the single entry point: one object owning the shared suite and trace
+cache, fronting every ranking/selection mode and the serving
 scheduler's step-cost models.  The legacy module-level call forms remain
 as one-release deprecation shims.
 

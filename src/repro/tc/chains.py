@@ -464,16 +464,15 @@ class ChainPredictor:
         return keys
 
     # ------------------------------------------------------------ rank --
-    def rank_paths(self, *, stat: str = "med",
-                   backend: str = "numpy") -> List[RankedChain]:
+    def rank_paths(self, *, stat: str = "med") -> List[RankedChain]:
         """All candidate paths, fastest-predicted chain total first.
 
         Per-step rankings run through the batched
-        :class:`~repro.core.predict.PredictionEngine` on ``backend``;
-        repeated calls reuse suite measurements and compiled batches.
+        :class:`~repro.core.predict.PredictionEngine`; repeated calls
+        reuse suite measurements and compiled batches.
         """
         return self._rank(stat, lambda step: self.step_predictor(step).rank(
-            stat=stat, backend=backend)[0])
+            stat=stat)[0])
 
     def rank_paths_oracle(self, *, stat: str = "med",
                           fresh: bool = True) -> List[RankedChain]:
@@ -511,10 +510,9 @@ class ChainPredictor:
         ranked.sort(key=lambda r: getattr(r.runtime, stat))
         return ranked
 
-    def select_path(self, *, stat: str = "med",
-                    backend: str = "numpy") -> RankedChain:
+    def select_path(self, *, stat: str = "med") -> RankedChain:
         """The fastest-predicted path (``rank_paths(...)[0]``)."""
-        return self.rank_paths(stat=stat, backend=backend)[0]
+        return self.rank_paths(stat=stat)[0]
 
     # ------------------------------------------------------------ cost --
     @property
@@ -550,7 +548,7 @@ class ChainSizeSweep(SizeSweep):
 
 def rank_einsum_sweep(chain: Union[ChainSpec, str],
                       sizes_grid: Sequence[Mapping[str, int]], *,
-                      stat: str = "med", backend: str = "numpy",
+                      stat: str = "med",
                       suite: Optional[MicroBenchmarkSuite] = None,
                       cache: Optional[TraceCache] = None,
                       repetitions: Optional[int] = None,
@@ -591,7 +589,7 @@ def rank_einsum_sweep(chain: Union[ChainSpec, str],
                                   memory_limit_bytes=memory_limit_bytes)
         except ValueError as e:
             raise ValueError(f"size point {sizes}: {e}") from None
-        rankings.append(tuple(pred.rank_paths(stat=stat, backend=backend)))
+        rankings.append(tuple(pred.rank_paths(stat=stat)))
         predictors.append(pred)
     return ChainSizeSweep(chain=spec, sizes_grid=tuple(grid),
                           rankings=tuple(rankings),

@@ -473,7 +473,7 @@ class DeviceSuite:
 
     # -------------------------------------------------------- persistence --
     def to_model_set(self) -> ModelSet:
-        """Measured device kernels + transfer models as one finalized
+        """Measured device kernels + transfer models as one
         :class:`ModelSet` — the payload of the store's ``__device__``
         name.  Per (kernel, VMEM classes): per-call-stat polynomials
         fitted over the measured config points (relative LS on the
@@ -517,7 +517,7 @@ class DeviceSuite:
                                       setup="tc-device")
                 pm.add_piece(_TRANSFER_CASE, model.to_piece())
                 ms.add(pm)
-        return ms.finalize()
+        return ms
 
     def load_model_set(self, ms: ModelSet) -> int:
         """Restore :meth:`to_model_set` output (a store warm start);

@@ -315,7 +315,7 @@ class ParametricModels:
 
     # ------------------------------------------------------- persistence --
     def to_model_set(self) -> ModelSet:
-        """All fitted models as one finalized :class:`ModelSet`.
+        """All fitted models as one :class:`ModelSet`.
 
         Per signature: the refined per-call pieces under case
         ``(classes, "percall")`` and the first-call constant (replicated
@@ -337,7 +337,7 @@ class ParametricModels:
             pm.add_piece((sig.classes, "first"),
                          Piece(domain=model.domain,
                                polys={s: model.first_poly for s in STATS}))
-        return ms.finalize()
+        return ms
 
     def load_model_set(self, ms: ModelSet) -> int:
         """Restore fitted models from :meth:`to_model_set` output (e.g. a

@@ -17,12 +17,12 @@ like any other configuration sweep of the PR-1/2 engine:
 3. the whole candidate set is compiled through the engine's
    :class:`TraceCache` into one reusable :class:`CompiledCalls` batch
    (the "block size" axis generalizes to the candidate index) and
-   predicted with ``backend="numpy"`` or ``"jax"``.
+   predicted in one engine evaluation.
 
 ``rank`` returns the traversal x kernel combinations sorted by predicted
 total runtime; ``rank_oracle`` is the un-deduplicated per-algorithm
 equivalence oracle.  With a deterministic ``measure_fn`` injected into the
-suite, both paths agree bit-for-bit on the numpy backend.
+suite, both paths agree bit-for-bit.
 
 Note the *cold-start* semantics of the total: ``first`` is the measured
 first-call overhead, which on this JAX substrate is dominated by XLA
@@ -107,7 +107,7 @@ class ContractionPredictor:
 
     ``prepare()`` (implicit on first use) runs the deduplicated suite and
     builds the per-signature models; ``rank``/``predict`` then evaluate the
-    whole candidate set through one compiled engine batch per backend —
+    whole candidate set through one compiled engine batch —
     repeated rankings reuse the suite measurements, the shared
     :class:`TraceCache` and the :class:`CompiledCalls` artifact, so they
     cost a few array ops, not a single kernel execution.
@@ -137,7 +137,7 @@ class ContractionPredictor:
                              f"{self.spec.einsum_expr()}")
         self.suite = resolve_suite(suite, repetitions)
         self.cache = cache if cache is not None else TraceCache()
-        self._engines: Dict[str, PredictionEngine] = {}
+        self._engine: Optional[PredictionEngine] = None
         self._models: Optional[ModelSet] = None
         self._benchmarks: List[MicroBenchmark] = []
         self._call_seqs: List[Tuple[KernelCall, ...]] = []
@@ -174,15 +174,13 @@ class ContractionPredictor:
                 model.add_piece(case, _signature_piece(mb))
             seqs.append((KernelCall(kernel=alg.kernel, case=case,
                                     sizes=(alg.n_iterations(self.sizes),)),))
-        # emit the padded case tensors now, like modelgen does: the first
-        # jax-backend rank should compile + dispatch, not derive tensors
-        self._models = models.finalize()
+        self._models = models
         self._benchmarks = benchmarks
         self._call_seqs = seqs
 
     @property
     def model_set(self) -> ModelSet:
-        """The finalized per-signature :class:`ModelSet` (prepares on
+        """The per-signature :class:`ModelSet` (prepares on
         first access) — the artifact a :class:`repro.store.ModelStore`
         persists alongside the raw measurements."""
         self.prepare()
@@ -194,27 +192,23 @@ class ContractionPredictor:
         return self._call_seqs[i]
 
     # ----------------------------------------------------------- predict --
-    def engine(self, backend: str = "numpy") -> PredictionEngine:
-        """The (shared-cache) engine for one backend; models built lazily."""
+    def engine(self) -> PredictionEngine:
+        """The (shared-cache) engine; models built lazily."""
         self.prepare()
-        eng = self._engines.get(backend)
-        if eng is None:
-            eng = PredictionEngine(self._models, backend=backend,
-                                   cache=self.cache)
-            self._engines[backend] = eng
-        return eng
+        if self._engine is None:
+            self._engine = PredictionEngine(self._models, cache=self.cache)
+        return self._engine
 
-    def predict(self, backend: str = "numpy") -> np.ndarray:
+    def predict(self) -> np.ndarray:
         """(n_algorithms, len(STATS)) predicted total runtimes."""
-        eng = self.engine(backend)
+        eng = self.engine()
         compiled = eng.compile_sweep(self._tracer, 0,
                                      range(len(self.algorithms)))
         return eng.predict_compiled(compiled)
 
-    def rank(self, *, stat: str = "med",
-             backend: str = "numpy") -> List[RankedContraction]:
+    def rank(self, *, stat: str = "med") -> List[RankedContraction]:
         """All traversal x kernel combinations, fastest-predicted first."""
-        arr = self.predict(backend)
+        arr = self.predict()
         col = STATS.index(stat)
         order = np.argsort(arr[:, col], kind="stable")
         return [RankedContraction(
@@ -322,7 +316,7 @@ class ContractionSizeSweep(SizeSweep):
 
 def rank_contraction_sweep(spec: Union[ContractionSpec, str],
                            sizes_grid: Sequence[Mapping[str, int]], *,
-                           stat: str = "med", backend: str = "numpy",
+                           stat: str = "med",
                            algorithms: Optional[
                                Sequence[ContractionAlgorithm]] = None,
                            include_batched: bool = True,
@@ -359,7 +353,7 @@ def rank_contraction_sweep(spec: Union[ContractionSpec, str],
         pred = ContractionPredictor(spec, sizes, algorithms=algs,
                                     suite=suite, cache=cache,
                                     arrival=arrival)
-        rankings.append(tuple(pred.rank(stat=stat, backend=backend)))
+        rankings.append(tuple(pred.rank(stat=stat)))
         predictors.append(pred)
     return ContractionSizeSweep(spec=spec, sizes_grid=tuple(grid),
                                 rankings=tuple(rankings),

@@ -4,24 +4,23 @@ The ranking entry points grew organically: every one of
 ``rank_contraction_algorithms`` / ``rank_einsum_paths`` /
 ``rank_contraction_sweep`` / ``rank_einsum_sweep`` /
 ``select_contraction_algorithm`` / ``select_einsum_path`` sprouted its own
-``backend=`` / ``suite=`` / ``cache=`` / ``repetitions=`` / ``sizes_grid=``
+``suite=`` / ``cache=`` / ``repetitions=`` / ``sizes_grid=``
 keywords, and sharing measurements across calls meant threading the same
 suite and trace cache through every call site by hand.
 
 :class:`PredictorSession` replaces that sprawl with ONE object that owns
-the four shared resources —
+the three shared resources —
 
 * the :class:`~repro.tc.suite.MicroBenchmarkSuite` (deduplicated
   cache-aware measurements, cost accounting),
 * the :class:`~repro.core.predict.TraceCache` (compiled sweep batches),
-* the evaluation **backend** (``"numpy"`` or ``"jax"``),
 * the per-(spec, sizes) predictor instances themselves (so a repeated
   ranking reuses the compiled :class:`~repro.core.predict.CompiledCalls`
   batch, not just the measurements)
 
 — and exposes every ranking/selection mode as a method.  Two sessions can
 still share measurements by passing one session's ``suite``/``cache`` into
-the other's constructor (e.g. a numpy and a jax session over one suite).
+the other's constructor.
 
 The legacy module-level call forms keep working for one release as thin
 deprecation shims that construct a session internally (see
@@ -71,12 +70,8 @@ def warn_deprecated_kwargs(fn: str, replacement: str,
 class PredictorSession:
     """Owns the shared prediction resources and every ranking entry point.
 
-    ``backend`` fixes how compiled batches are evaluated (``"numpy"`` or
-    ``"jax"``) for every method of this session; build a second session
-    over the same ``suite``/``cache`` to compare backends without
-    re-measuring or re-tracing.  ``repetitions`` configures a freshly
-    built suite and conflicts with passing ``suite=`` (the suite owns its
-    measurement protocol — see
+    ``repetitions`` configures a freshly built suite and conflicts with
+    passing ``suite=`` (the suite owns its measurement protocol — see
     :func:`~repro.tc.suite.resolve_suite`).
 
     Predictors are memoized per (spec, sizes, candidate-set) signature:
@@ -95,15 +90,13 @@ class PredictorSession:
     session brings its registry along.
     """
 
-    def __init__(self, *, backend: str = "numpy",
-                 suite: Optional[MicroBenchmarkSuite] = None,
+    def __init__(self, *, suite: Optional[MicroBenchmarkSuite] = None,
                  cache: Optional[TraceCache] = None,
                  repetitions: Optional[int] = None,
                  store=None, allow_mismatch: bool = False,
                  parametric: bool = False,
                  parametric_error_bound: float = 0.05,
                  parametric_budget: Optional[int] = 32):
-        self.backend = backend
         param_sets = None
         if store is not None:
             # warm start from a repro.store.ModelStore (object or path):
@@ -213,7 +206,7 @@ class PredictorSession:
                                           algorithms=algorithms,
                                           include_batched=include_batched,
                                           arrival=arrival)
-        return pred.rank(stat=stat, backend=self.backend)
+        return pred.rank(stat=stat)
 
     def select_contraction_algorithm(
             self, spec: Union[ContractionSpec, str],
@@ -246,9 +239,9 @@ class PredictorSession:
         # kwargs were deprecated in favor of
         # reprolint: allow[deprecated-kwarg]
         return rank_contraction_sweep(
-            spec, sizes_grid, stat=stat, backend=self.backend,
-            algorithms=algorithms, include_batched=include_batched,
-            suite=self.suite, cache=self.cache, arrival=arrival)
+            spec, sizes_grid, stat=stat, algorithms=algorithms,
+            include_batched=include_batched, suite=self.suite,
+            cache=self.cache, arrival=arrival)
 
     # ----------------------------------------------------------- chains --
     def rank_einsum_paths(self, chain, sizes: Mapping[str, int], *,
@@ -265,7 +258,7 @@ class PredictorSession:
                                     kernels=kernels,
                                     max_loop_perms=max_loop_perms,
                                     memory_limit_bytes=memory_limit_bytes)
-        return pred.rank_paths(stat=stat, backend=self.backend)
+        return pred.rank_paths(stat=stat)
 
     def select_einsum_path(self, chain, sizes: Mapping[str, int], *,
                            stat: str = "med",
@@ -295,8 +288,7 @@ class PredictorSession:
         # kwargs were deprecated in favor of
         # reprolint: allow[deprecated-kwarg]
         return rank_einsum_sweep(
-            chain, sizes_grid, stat=stat, backend=self.backend,
-            suite=self.suite, cache=self.cache,
+            chain, sizes_grid, stat=stat, suite=self.suite, cache=self.cache,
             include_batched=include_batched, kernels=kernels,
             max_loop_perms=max_loop_perms,
             memory_limit_bytes=memory_limit_bytes)

@@ -216,7 +216,7 @@ def test_deprecated_kwarg_session_form_is_clean():
 
 def test_deprecated_kwarg_explicit_none_forwarding_is_clean():
     assert not findings_of("deprecated-kwarg", """
-        ranked = rank_einsum_paths(chain, sizes, backend=None,
+        ranked = rank_einsum_paths(chain, sizes, repetitions=None,
                                    session=sess)
     """)
 
@@ -232,11 +232,12 @@ def test_deprecated_call_findings_reusable_entry():
 
 # ----------------------------------------------------------- oracle-coverage --
 
-def _oracle_repo(tmp_path: Path, test_body: str) -> Path:
+def _oracle_repo(tmp_path: Path, test_body: str,
+                 entry: str = "select_algorithm") -> Path:
     (tmp_path / "src").mkdir()
     (tmp_path / "tests").mkdir()
-    (tmp_path / "src" / "sel.py").write_text(textwrap.dedent("""
-        def select_algorithm(tracers, models, n, b):
+    (tmp_path / "src" / "sel.py").write_text(textwrap.dedent(f"""
+        def {entry}(*args, **kwargs):
             return "x"
     """))
     (tmp_path / "tests" / "test_sel.py").write_text(
@@ -244,10 +245,10 @@ def _oracle_repo(tmp_path: Path, test_body: str) -> Path:
     return tmp_path
 
 
-def _oracle_findings(root: Path):
+def _oracle_findings(root: Path, entry: str = "select_algorithm"):
     ctxs = [FileContext("src/sel.py", (root / "src" / "sel.py").read_text())]
     return [f for f in REGISTRY["oracle-coverage"].check_repo(ctxs, root)
-            if "select_algorithm" in f.message]
+            if entry in f.message]
 
 
 def test_oracle_coverage_flags_untested_entry_point(tmp_path):
@@ -268,12 +269,13 @@ def test_oracle_coverage_flags_unpinned_fast_path(tmp_path):
 
 
 def test_oracle_coverage_oracle_kwarg_satisfies(tmp_path):
+    entry = "rank_contraction_algorithms"
     out = _oracle_findings(_oracle_repo(tmp_path, """
-        def test_select():
-            got = select_algorithm(t, m, 512, 64)
-            ref = select_algorithm(t, m, 512, 64, batched=False)
+        def test_rank():
+            got = rank_contraction_algorithms(spec, sizes)
+            ref = rank_contraction_algorithms(spec, sizes, batched=False)
             assert got == ref
-    """))
+    """, entry), entry)
     assert not out
 
 
@@ -352,7 +354,6 @@ _COMPARE_PY = """
     UNTRACKED = (
         ("alpha", "alpha_points"),
     )
-    BACKEND_RATIOS = ()
     SERVING_RATIOS = ()
 """
 

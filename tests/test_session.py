@@ -43,7 +43,7 @@ def test_session_contraction_ranking_matches_predictor():
     sess = fake_session()
     direct = ContractionPredictor(SPEC, SIZES, suite=fake_suite())
     got = sess.rank_contraction_algorithms(SPEC, SIZES)
-    want = direct.rank(stat="med", backend="numpy")
+    want = direct.rank(stat="med")
     assert [r.name for r in got] == [r.name for r in want]
     np.testing.assert_allclose([r.runtime.med for r in got],
                                [r.runtime.med for r in want])
@@ -59,7 +59,7 @@ def test_session_chain_ranking_matches_predictor():
     sess = fake_session()
     direct = ChainPredictor(CHAIN, CHAIN_SIZES, suite=fake_suite())
     got = sess.rank_einsum_paths(CHAIN, CHAIN_SIZES)
-    want = direct.rank_paths(stat="med", backend="numpy")
+    want = direct.rank_paths(stat="med")
     assert [r.name for r in got] == [r.name for r in want]
     assert sess.select_einsum_path(CHAIN, CHAIN_SIZES).name == \
         got[0].name
@@ -168,7 +168,7 @@ def test_session_conflicts_with_legacy_kwargs():
                                     session=sess, suite=fake_suite())
     with pytest.raises(ValueError, match="session"):
         select_contraction_algorithm(SPEC, SIZES, session=sess,
-                                     backend="numpy")
+                                     repetitions=3)
     pred = ChainPredictor(CHAIN, CHAIN_SIZES, suite=fake_suite())
     with pytest.raises(ValueError, match="session"):
         rank_einsum_paths(CHAIN, CHAIN_SIZES, session=sess,

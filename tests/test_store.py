@@ -446,20 +446,6 @@ def test_performance_model_from_dict_freezes_nested_cases(tmp_path):
     assert loaded.estimate(case, (16,)) == m.estimate(case, (16,))
 
 
-def test_performance_model_load_refinalizes_padded_tensors(tmp_path):
-    m, case = _quadratic_model()
-    m.finalize()
-    path = tmp_path / "model.json"
-    m.save(str(path))
-    loaded = PerformanceModel.load(str(path))
-    # from_dict re-finalized: the padded case tensors are already built
-    cm = loaded.cases[case]
-    assert getattr(cm, "_jax_cache", None) is not None
-    for got, ref in zip(cm.padded_tensors(),
-                        m.cases[case].padded_tensors()):
-        np.testing.assert_array_equal(got, ref)
-
-
 def test_model_set_save_load_predict_compiled_bit_equal(tmp_path):
     """The regression the store layer depends on: ModelSet artifacts
     survive a save/load cycle, proven by BIT-equal ``predict_compiled``
@@ -467,14 +453,12 @@ def test_model_set_save_load_predict_compiled_bit_equal(tmp_path):
     models = catalog_synthetic_model_set()
     seqs = [tracer(264, 56) for tracer in ALL_TRACERS.values()]
     compiled = compile_calls(seqs)
-    before = PredictionEngine(models,
-                              backend="numpy").predict_compiled(compiled)
+    before = PredictionEngine(models).predict_compiled(compiled)
     path = tmp_path / "models.json"
     models.save(str(path))
     loaded = ModelSet.load(str(path))
     assert set(loaded.models) == set(models.models)
-    after = PredictionEngine(loaded,
-                             backend="numpy").predict_compiled(compiled)
+    after = PredictionEngine(loaded).predict_compiled(compiled)
     np.testing.assert_array_equal(after, before)
 
 

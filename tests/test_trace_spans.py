@@ -15,7 +15,9 @@ import numpy as np
 import pytest
 
 from benchmarks.common import catalog_synthetic_model_set
-from repro.core import PredictionEngine, optimize_algorithm_and_block_size
+from repro.core import (PredictionEngine, optimize_algorithm_and_block_size,
+                        predict_runtime)
+from repro.core import predict
 from repro.core.predict import TraceCache
 from repro.dla import ExecEngine, Matrix, blocked, kernels as K, trace_calls
 from repro.dla.tracers import CHOLESKY_TRACERS, potrf_tracer
@@ -215,11 +217,17 @@ def test_trace_cache_spans_each_miss_and_no_hit(tmp_path):
     assert (cache.misses, cache.hits) == (2, 1)
 
 
-@pytest.mark.parametrize("backend", ["numpy", "jax"])
-def test_selection_traces_compiles_and_evaluates_in_spans(tmp_path,
-                                                          backend):
+@pytest.mark.parametrize("engine", ["implicit", "own"])
+def test_selection_traces_compiles_and_evaluates_in_spans(tmp_path, engine):
+    """A first selection traces each configuration, compiles the batch
+    once and evaluates it once, on the shared bounded cache of engine-less
+    calls as on a caller's own engine."""
     models = catalog_synthetic_model_set()
-    engine = PredictionEngine(models, backend=backend)
+    if engine == "own":
+        engine = PredictionEngine(models)
+    else:
+        engine = None
+        predict._IMPLICIT_CACHE.clear()
     candidates = [32, 64]
     out = {}
 
@@ -234,7 +242,7 @@ def test_selection_traces_compiles_and_evaluates_in_spans(tmp_path,
     assert [st["b"] for _, _, st in spans[:configs]] == \
         candidates * len(CHOLESKY_TRACERS)
     assert spans[-2][2] == {"configs": configs}
-    assert spans[-1][2] == {"backend": backend}
+    assert spans[-1][2] == {}
     assert out["pick"][0] in CHOLESKY_TRACERS
 
 
@@ -255,13 +263,14 @@ def test_default_engine_selection_evaluates_a_cached_batch(tmp_path, n):
 
     spans = recorded_spans(tmp_path, work)
     assert [(name, st) for _, name, st in spans] == \
-        [("predict.evaluate", {"backend": "numpy"})]
+        [("predict.evaluate", {})]
     fresh = optimize_algorithm_and_block_size(
         CHOLESKY_TRACERS, models, n, candidates,
         engine=PredictionEngine(models))
     assert out["pick"] == fresh
-    oracle = optimize_algorithm_and_block_size(
-        CHOLESKY_TRACERS, models, n, candidates, batched=False)
+    oracle = min(((name, b, predict_runtime(tracer(n, b), models).med)
+                  for name, tracer in CHOLESKY_TRACERS.items()
+                  for b in candidates), key=lambda pick: pick[2])
     assert out["pick"][:2] == oracle[:2]
     assert out["pick"][2] == pytest.approx(oracle[2], rel=1e-10)
 

@@ -20,8 +20,8 @@ list):
   directly above a fence to exclude a block (e.g. pseudocode);
 * **deprecated kwargs** — python snippets must not call the legacy
   prediction entry points with the kwargs the ``PredictorSession``
-  redesign deprecated (``suite=``/``cache=``/``backend=``/
-  ``repetitions=``/``sizes_grid=``/``predictor=``): docs are the first
+  redesign deprecated (``suite=``/``cache=``/``repetitions=``/
+  ``sizes_grid=``/``predictor=``): docs are the first
   thing readers copy, so the old API must not reappear in examples.
 
 Exit code 0 when everything passes; 1 with a per-finding report

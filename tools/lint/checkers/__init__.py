@@ -2,8 +2,8 @@
 
 One module per checker; each encodes one standing ROADMAP invariant:
 
-* :mod:`.host_sync` — hot paths stay dispatch-free (PR 5/6 fused engine
-  + sub-ms scheduler ticks);
+* :mod:`.host_sync` — hot paths stay dispatch-free (the serve engine's
+  fused step + sub-ms scheduler ticks);
 * :mod:`.retrace` — jit call sites declare Python-config params static
   (one compile per platform, not one per config value);
 * :mod:`.deprecated_kwargs` — every ranking entry point goes through

@@ -1,8 +1,8 @@
 """deprecated-kwarg: ranking entry points go through PredictorSession.
 
 PR 6 unified every ranking/selection entry point behind
-:class:`repro.tc.PredictorSession` — one object owning the suite, trace
-cache and backend — and deprecated the per-call resource kwargs behind
+:class:`repro.tc.PredictorSession` — one object owning the suite and
+trace cache — and deprecated the per-call resource kwargs behind
 one-release shims.  The shims keep old *external* callers working, but
 internal code, benchmarks, examples and docs must not keep the legacy
 spelling alive: every such call builds a throwaway session, re-measures
@@ -28,14 +28,14 @@ from ..core import Checker, FileContext, Finding, register
 #: (`session=` replaced them; tools/check_docs.py reuses this table for
 #: docs snippets, so source and docs can never disagree on the rule.)
 DEPRECATED_KWARGS: Mapping[str, Sequence[str]] = {
-    "rank_contraction_algorithms": ("suite", "cache", "backend",
-                                    "repetitions", "sizes_grid"),
-    "select_contraction_algorithm": ("backend", "repetitions", "predictor"),
-    "rank_einsum_paths": ("suite", "cache", "backend", "repetitions",
-                          "sizes_grid", "predictor"),
-    "select_einsum_path": ("backend", "repetitions", "predictor"),
-    "rank_contraction_sweep": ("suite", "cache", "backend", "repetitions"),
-    "rank_einsum_sweep": ("suite", "cache", "backend", "repetitions"),
+    "rank_contraction_algorithms": ("suite", "cache", "repetitions",
+                                    "sizes_grid"),
+    "select_contraction_algorithm": ("repetitions", "predictor"),
+    "rank_einsum_paths": ("suite", "cache", "repetitions", "sizes_grid",
+                          "predictor"),
+    "select_einsum_path": ("repetitions", "predictor"),
+    "rank_contraction_sweep": ("suite", "cache", "repetitions"),
+    "rank_einsum_sweep": ("suite", "cache", "repetitions"),
 }
 
 
@@ -68,12 +68,12 @@ def deprecated_call_findings(tree: ast.AST, rel: str,
                 f"{fn}() called with deprecated kwarg(s) "
                 f"{', '.join(k + '=' for k in sorted(used))} — construct "
                 f"a repro.tc.PredictorSession and use its methods "
-                f"(session= owns the suite/cache/backend)"))
+                f"(session= owns the suite/cache)"))
     return out
 
 
 def _is_none(node: ast.expr) -> bool:
-    """Forwarding ``backend=None`` explicitly is the shim plumbing's own
+    """Forwarding ``repetitions=None`` explicitly is the shim plumbing's own
     idiom and behaviorally identical to omitting the kwarg."""
     return isinstance(node, ast.Constant) and node.value is None
 
@@ -83,7 +83,7 @@ class DeprecatedKwargChecker(Checker):
     id = "deprecated-kwarg"
     description = ("no internal/bench/example/docs call to a ranking "
                    "entry point with the PR-6-deprecated per-call "
-                   "kwargs (suite=/cache=/backend=/predictor=/...)")
+                   "kwargs (suite=/cache=/predictor=/...)")
 
     def check_file(self, ctx: FileContext) -> Iterable[Finding]:
         return deprecated_call_findings(ctx.tree, ctx.rel, self.id)

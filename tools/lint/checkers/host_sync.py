@@ -1,11 +1,10 @@
 """host-sync: no device synchronization inside dispatch-free hot paths.
 
 The paper's promise — predictions "at merely a fraction of a
-contraction's runtime" — survives only while the hot paths stay fused
-and dispatch-free: PR 5 fused the whole compiled-batch evaluation into
-ONE XLA program precisely to eliminate host round-trips, and PR 6's
-scheduler tick budget (< 1 ms) assumes planning never blocks on the
-device.  One stray ``block_until_ready`` (or a ``float()`` /
+contraction's runtime" — survives only while the hot paths stay
+dispatch-free: the serve engine's step hooks run one fused step each,
+and the scheduler tick budget (< 1 ms) assumes planning never blocks on
+the device.  One stray ``block_until_ready`` (or a ``float()`` /
 ``np.asarray`` D2H pull) re-serializes the pipeline and the regression
 is silent until a benchmark notices.
 

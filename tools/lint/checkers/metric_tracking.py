@@ -15,8 +15,8 @@ closes the loop statically (pure AST, no jax import, so it fits the
   assignments — non-literal keys are themselves flagged, since a key
   the linter cannot read is a key the tables cannot list);
 * parse ``benchmarks/compare_smoke.py`` for the declarative ``METRICS``
-  / ``UNTRACKED`` tables (plus the ``BACKEND_RATIOS`` /
-  ``SERVING_RATIOS`` metric references, which count as known);
+  / ``UNTRACKED`` tables (plus the ``SERVING_RATIOS`` metric
+  references, which count as known);
 * flag emitted-but-unknown keys at their emit site, table entries no
   bench emits anymore (stale rows), and unit-suffix aliases — timings
   are ``_s``/``_ms``, rates ``_tok_s``, ratios-of-totals ``_frac``.
@@ -159,11 +159,10 @@ class MetricTrackingChecker(Checker):
                 _assigned_literal(cmp_ctx.tree, "UNTRACKED")):
             known.setdefault(suite, set()).add(metric)
             tracked_rows.append((suite, metric))
-        for table in ("BACKEND_RATIOS", "SERVING_RATIOS"):
-            for row in _tuple_rows(_assigned_literal(cmp_ctx.tree, table)):
-                suite = row[0]
-                for metric in row[1:]:
-                    known.setdefault(suite, set()).add(metric)
+        for row in _tuple_rows(_assigned_literal(cmp_ctx.tree,
+                                                 "SERVING_RATIOS")):
+            for metric in row[1:]:
+                known.setdefault(row[0], set()).add(metric)
 
         emitted: Dict[str, Set[str]] = {}
         for suite, mod in sorted(_smoke_modules(run_ctx.tree).items()):

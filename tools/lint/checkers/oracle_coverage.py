@@ -8,9 +8,9 @@ This checker makes the convention structural: for each ranking/
 prediction entry point in :data:`ORACLE_PAIRS`, at least one module
 under ``tests/`` must both invoke the entry point AND invoke one of its
 oracle forms.  An oracle form is either a called name
-(``rank_oracle``, ``predict_compiled_grouped``, ``FifoScheduler``) or
-the ``batched=False`` keyword that switches a selection entry point onto
-the scalar path.
+(``predict_runtime``, ``rank_oracle``, ``FifoScheduler``) or the
+``batched=False`` keyword that switches a contraction entry point onto
+its per-algorithm path.
 
 Findings anchor at the entry point's ``def``/``class`` site in ``src/``
 — the owner of an uncovered fast path is the code, not the test suite.
@@ -29,12 +29,12 @@ from ..core import Checker, FileContext, Finding, register
 #: literal keyword (the scalar-path switch).  Kept declarative so a new
 #: fast path is one line here + one test, per the architecture.md table.
 ORACLE_PAIRS: Mapping[str, Sequence[str]] = {
-    # blocked-algorithm selection (paper §4.5/§4.6) vs the scalar path
-    "rank_algorithms": ("predict_runtime", "batched=False"),
-    "select_algorithm": ("predict_runtime", "batched=False"),
-    "optimize_block_size": ("predict_runtime", "batched=False"),
-    # fused one-dispatch engine vs the per-(kernel, case) grouped path
-    "predict_compiled": ("predict_compiled_grouped",),
+    # blocked-algorithm selection (paper §4.5/§4.6) and the batched
+    # engine under it vs the scalar per-call path
+    "rank_algorithms": ("predict_runtime",),
+    "select_algorithm": ("predict_runtime",),
+    "optimize_block_size": ("predict_runtime",),
+    "predict_compiled": ("predict_runtime",),
     # contraction ranking (Ch. 6) vs the fresh per-algorithm §6.2 oracle
     "rank_contraction_algorithms": ("rank_oracle", "batched=False"),
     "select_contraction_algorithm": ("rank_oracle", "batched=False"),

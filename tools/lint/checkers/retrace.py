@@ -1,7 +1,7 @@
 """retrace: jit sites must declare Python-config parameters static.
 
-The engine's "compile once per platform, predict forever" economics
-(PR 2's TraceCache, PR 5's fused one-dispatch program) die quietly when
+The "compile once per platform, run forever" economics of the jitted
+kernel, tile and serve-step programs die quietly when
 a jit boundary treats a Python config value as a traced operand: every
 new bool/str/tuple value either retraces the whole program or raises a
 ``TracerBoolConversionError`` deep inside the body.  Both hazards are
